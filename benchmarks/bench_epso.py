@@ -37,7 +37,7 @@ if os.path.join(ROOT, "src") not in sys.path:      # direct-script invocation
 import jax
 import numpy as np
 
-from repro.compat import AxisType  # installs old-jax shims on import
+from jax.sharding import AxisType
 from jax.sharding import AbstractMesh
 
 from repro.configs import get_config

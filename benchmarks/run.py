@@ -19,8 +19,6 @@ def main() -> None:
                     help="subset of bench names (fsmoe epso scaling loss kernels)")
     args = ap.parse_args()
 
-    from repro import compat as _compat  # noqa: F401  old-jax shims
-
     from . import (bench_epso, bench_fsmoe, bench_kernels, bench_loss,
                    bench_scaling, bench_serve)
     benches = {"kernels": bench_kernels, "epso": bench_epso,

@@ -7,9 +7,9 @@ is installed, and it must stay runnable on a bare interpreter.
 =====  ====================================================================
 rule   what it forbids (and the incident behind it)
 =====  ====================================================================
-SL001  importing ``jax.experimental.shard_map`` anywhere but
-       ``compat.py`` — ``compat.manual_shard_map`` owns the 0.4.x
-       partial-auto shims; a raw import silently loses them
+SL001  importing ``jax.experimental.shard_map`` anywhere (no allowlist)
+       — the experimental module is the pre-0.5 API; every region uses
+       ``jax.shard_map`` with ``axis_names``/``check_vma``
 SL002  ``ragged_dot`` outside the documented allowlist
        (``kernels/ref.py``) — XLA's SPMD partitioner rewrites its
        group_sizes operand incorrectly on ep/tp meshes (PR 6)
@@ -36,7 +36,7 @@ from typing import Iterator, List, Tuple
 
 # rule -> path suffixes where the construct is the documented owner
 ALLOWLIST = {
-    "SL001": ("src/repro/compat.py",),
+    "SL001": (),
     "SL002": ("src/repro/kernels/ref.py",),
     "SL003": (),
     # SL004 has no owners left: the PR 4 aliases are deleted, the symbols
@@ -109,26 +109,24 @@ def lint_source(source: str, path: str, *,
             for a in node.names:
                 if a.name.startswith("jax.experimental.shard_map"):
                     emit("SL001", node,
-                         f"import {a.name}: use compat.manual_shard_map "
-                         f"(owns the partial-auto shims)")
+                         f"import {a.name}: use jax.shard_map")
         elif isinstance(node, ast.ImportFrom):
             mod = node.module or ""
             if mod.startswith("jax.experimental.shard_map"):
                 emit("SL001", node,
-                     f"from {mod} import ...: use "
-                     f"compat.manual_shard_map")
+                     f"from {mod} import ...: use jax.shard_map")
             elif mod == "jax.experimental" and any(
                     a.name == "shard_map" for a in node.names):
                 emit("SL001", node,
                      "from jax.experimental import shard_map: use "
-                     "compat.manual_shard_map")
+                     "jax.shard_map")
 
         dotted = _dotted(node) if isinstance(node, ast.Attribute) else ""
 
         # SL001 — attribute use without import (jax.experimental.shard_map.x)
         if dotted.startswith("jax.experimental.shard_map"):
             emit("SL001", node,
-                 f"{dotted}: use compat.manual_shard_map")
+                 f"{dotted}: use jax.shard_map")
 
         # SL002 — ragged_dot outside the allowlist
         if isinstance(node, ast.Attribute) and node.attr == "ragged_dot":

@@ -14,3 +14,12 @@ test_ssd_kernel.py).
   flash_attention.py  blockwise online-softmax attention (causal + SWA)
   ssd.py              Mamba-2 SSD intra-chunk stage (hybrid archs)
 """
+import jax
+
+
+def out_struct(shape, dtype, *operands):
+    """A ``pallas_call`` out_shape that varies over the manual mesh axes its
+    operands vary over. Inside a ``check_vma`` shard_map region (the EP MoE
+    body) a kernel output must declare them; elsewhere the set is empty."""
+    vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

@@ -16,11 +16,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import out_struct
+
 
 def _combine_fwd_kernel(rows_ref, w_ref, out_ref):
     rows = rows_ref[...].astype(jnp.float32)     # (TT, K, TD)
     w = w_ref[...].astype(jnp.float32)           # (TT, K)
-    out_ref[...] = jnp.einsum("tkd,tk->td", rows, w).astype(out_ref.dtype)
+    # broadcast multiply + K-reduction on the VPU: Mosaic has no batched
+    # matmul lowering for a (TT, K, TD) x (TT, K) contraction
+    out_ref[...] = (rows * w[:, :, None]).sum(axis=1).astype(out_ref.dtype)
 
 
 def combine_fwd_pallas(rows: jax.Array, weights: jax.Array, *,
@@ -35,7 +39,7 @@ def combine_fwd_pallas(rows: jax.Array, weights: jax.Array, *,
         in_specs=[pl.BlockSpec((tt, K, td), lambda t, d: (t, 0, d)),
                   pl.BlockSpec((tt, K), lambda t, d: (t, 0))],
         out_specs=pl.BlockSpec((tt, td), lambda t, d: (t, d)),
-        out_shape=jax.ShapeDtypeStruct((T, D), rows.dtype),
+        out_shape=out_struct((T, D), rows.dtype, rows, weights),
         interpret=interpret,
     )(rows, weights)
 
@@ -70,7 +74,7 @@ def combine_bwd_pallas(rows: jax.Array, weights: jax.Array, dout: jax.Array,
                   pl.BlockSpec((tt, td), lambda t, d: (t, d))],
         out_specs=[pl.BlockSpec((tt, K, td), lambda t, d: (t, 0, d)),
                    pl.BlockSpec((tt, K), lambda t, d: (t, 0))],
-        out_shape=[jax.ShapeDtypeStruct((T, K, D), rows.dtype),
-                   jax.ShapeDtypeStruct((T, K), jnp.float32)],
+        out_shape=[out_struct((T, K, D), rows.dtype, rows, weights, dout),
+                   out_struct((T, K), jnp.float32, rows, weights, dout)],
         interpret=interpret,
     )(rows, weights, dout)

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import out_struct
+
 NEG = -1e30
 
 
@@ -88,7 +90,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                   pl.BlockSpec((1, kb, hd), lambda b, i, j: (b, j, 0)),
                   pl.BlockSpec((1, kb, hd), lambda b, i, j: (b, j, 0))],
         out_specs=pl.BlockSpec((1, qb, hd), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, Sq + pq, hd), q.dtype),
+        out_shape=out_struct((BH, Sq + pq, hd), q.dtype, qp, kp, vp),
         scratch_shapes=[pltpu.VMEM((qb,), jnp.float32),
                         pltpu.VMEM((qb,), jnp.float32),
                         pltpu.VMEM((qb, hd), jnp.float32)],

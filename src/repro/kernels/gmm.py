@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import out_struct
+
 
 def _gmm_kernel(group_ids_ref, lhs_ref, rhs_ref, out_ref, acc_ref, *,
                 n_k: int):
@@ -63,7 +65,7 @@ def gmm_pallas(lhs: jax.Array, rhs: jax.Array, group_ids: jax.Array, *,
     return pl.pallas_call(
         functools.partial(_gmm_kernel, n_k=n_k),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
+        out_shape=out_struct((M, N), lhs.dtype, lhs, rhs, group_ids),
         interpret=interpret,
     )(group_ids, lhs, rhs)
 
@@ -125,6 +127,7 @@ def tgmm_pallas(lhs: jax.Array, rhs: jax.Array, group_ids: jax.Array,
     return pl.pallas_call(
         functools.partial(_tgmm_kernel, n_m=n_m),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_groups, K, N), lhs.dtype),
+        out_shape=out_struct((num_groups, K, N), lhs.dtype, lhs, rhs,
+                             group_ids),
         interpret=interpret,
     )(group_ids, lhs, rhs)

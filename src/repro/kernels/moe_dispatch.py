@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import out_struct
+
 
 def _count_kernel(idx_ref, out_ref, *, num_local: int):
     t = pl.program_id(0)
@@ -48,6 +50,6 @@ def token_counts_pallas(indices: jax.Array, num_local: int, offset, *,
         grid=((F + pad) // tb,),
         in_specs=[pl.BlockSpec((1, tb), lambda t: (0, t))],
         out_specs=pl.BlockSpec((num_local,), lambda t: (0,)),
-        out_shape=jax.ShapeDtypeStruct((num_local,), jnp.int32),
+        out_shape=out_struct((num_local,), jnp.int32, local),
         interpret=interpret,
     )(local)

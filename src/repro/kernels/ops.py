@@ -183,10 +183,17 @@ def _tile_d(D):
     return 1
 
 
-def _combine_tiles(T, K, D):
+# Largest (tt, K, td) rows block: the backward kernel double-buffers it in
+# and out (4 blocks), which must fit the 16 MiB of scoped VMEM a TPU kernel
+# gets by default. 2 MiB is a (256, 8, 512) bf16 block; a float32 one halves tt.
+_COMBINE_BLOCK_BYTES = 2 << 20
+
+
+def _combine_tiles(T, K, D, itemsize):
     """Divisor-scan defaults, overridden by the tuning table under
     ``tiles='auto'`` when the table tiles divide the actual dims (these
-    wrappers don't pad, so non-divisors fall back)."""
+    wrappers don't pad, so non-divisors fall back); then tt halves (it stays
+    a power-of-two divisor of T) until the rows block fits the VMEM budget."""
     tt, td = _tile_t(T), _tile_d(D)
     auto = current_kernel_plan().resolve_tiles(
         "combine", {"t": T, "k": K, "d": D})
@@ -196,12 +203,15 @@ def _combine_tiles(T, K, D):
             tt = at
         if ad and D % ad == 0:
             td = ad
+    while tt > 8 and tt % 2 == 0 and tt * K * td * itemsize > \
+            _COMBINE_BLOCK_BYTES:
+        tt //= 2
     return tt, td
 
 
 def _combine_fwd_impl(rows, weights):
     T, K, D = rows.shape
-    tt, td = _combine_tiles(T, K, D)
+    tt, td = _combine_tiles(T, K, D, rows.dtype.itemsize)
     return combine_fwd_pallas(rows, weights, tile_t=tt, tile_d=td,
                               interpret=_interpret())
 
@@ -213,7 +223,7 @@ def _combine_fwd(rows, weights):
 def _combine_bwd(res, dout):
     rows, weights = res
     T, K, D = rows.shape
-    tt, td = _combine_tiles(T, K, D)
+    tt, td = _combine_tiles(T, K, D, rows.dtype.itemsize)
     drows, dw = combine_bwd_pallas(rows, weights, dout, tile_t=tt,
                                    tile_d=td, interpret=_interpret())
     return drows, dw.astype(weights.dtype)

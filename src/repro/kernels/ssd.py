@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import out_struct
+
 
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref,
                 y_ref, st_ref, cd_ref):
@@ -56,6 +58,7 @@ def ssd_intra_chunk_pallas(x, dt, Bm, Cm, A, *, interpret: bool = False):
     Returns (y_diag (B,C,L,H,P), states (B,C,H,P,N), chunk_decay (B,C,H))."""
     B, C, L, H, P = x.shape
     N = Bm.shape[-1]
+    ops = (x, dt, Bm, Cm, A)
     y, st, cd = pl.pallas_call(
         _ssd_kernel,
         grid=(B, C, H),
@@ -72,9 +75,9 @@ def ssd_intra_chunk_pallas(x, dt, Bm, Cm, A, *, interpret: bool = False):
             pl.BlockSpec((1, 1, 1), lambda b, c, h: (b, c, h)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, C, L, H, P), jnp.float32),
-            jax.ShapeDtypeStruct((B, C, H, P, N), jnp.float32),
-            jax.ShapeDtypeStruct((B, C, H), jnp.float32),
+            out_struct((B, C, L, H, P), jnp.float32, *ops),
+            out_struct((B, C, H, P, N), jnp.float32, *ops),
+            out_struct((B, C, H), jnp.float32, *ops),
         ],
         interpret=interpret,
     )(x, dt, Bm, Cm, A.astype(jnp.float32))

@@ -11,6 +11,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import out_struct
+
 
 def _swiglu_kernel(g_ref, u_ref, out_ref):
     g = g_ref[...].astype(jnp.float32)
@@ -29,6 +31,6 @@ def swiglu_pallas(gate: jax.Array, up: jax.Array, *, tile_m: int = 512,
         in_specs=[pl.BlockSpec((tm, tn), lambda i, j: (i, j)),
                   pl.BlockSpec((tm, tn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), gate.dtype),
+        out_shape=out_struct((M, N), gate.dtype, gate, up),
         interpret=interpret,
     )(gate, up)

@@ -49,8 +49,6 @@ def _probe(fn, args, out_shardings=None):
         else jax.jit(fn)
     compiled = jitted.lower(*args).compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # old jax: one dict per executable
-        cost = cost[0] if cost else {}
     coll = RL.collective_bytes(compiled.as_text())
     return (float(cost.get("flops", 0.0)),
             float(cost.get("bytes accessed", 0.0)), coll)

@@ -6,7 +6,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import AxisType  # installs old-jax shims on import
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,7 +25,7 @@ def make_host_mesh(shape=None, axes=("data", "model")):
 
 
 # ---------------------------------------------------------------------------
-# simulated multi-device CPU meshes (the --mesh launcher path)
+# launcher meshes (--mesh / --parallel): CPU host devices or accelerators
 # ---------------------------------------------------------------------------
 
 _FORCE_FLAG = "--xla_force_host_platform_device_count"
@@ -74,26 +74,33 @@ def forced_device_env(n: int, env=None) -> dict:
 
 
 def make_forced_mesh(shape, axes, *, what: str = None):
-    """Mesh over forced CPU host devices — the one shared constructor behind
-    the legacy ``--mesh`` path (make_sim_mesh) and ``ParallelPlan.resolve``,
-    so the forced-device contract and its error message can never diverge
-    between the two. Raises with the exact XLA_FLAGS fix if the backend
-    came up with too few devices."""
+    """Mesh over the default backend's devices — the one shared constructor
+    behind the legacy ``--mesh`` path (make_sim_mesh) and
+    ``ParallelPlan.resolve``, so the device contract and its error message
+    can never diverge between the two. On the CPU platform it first asks
+    for enough host devices (effective only before the backend starts);
+    an accelerator has the devices it has, and too few is an error."""
     n = 1
     for d in shape:
         n *= d
-    ensure_host_devices(n)
-    ndev = len(jax.devices())
-    if ndev < n:
-        raise RuntimeError(
-            f"{what or f'mesh {tuple(shape)}'} needs {n} devices but jax "
-            f"sees {ndev}; the backend initialized before the mesh request "
-            f"— launch with XLA_FLAGS='{_FORCE_FLAG}={n}' in the "
-            f"environment")
+    requested = (jax.config.jax_platforms or "").split(",")[0]
+    if requested in ("", "cpu"):      # the flag only reaches the CPU client
+        ensure_host_devices(n)
+    devices = jax.devices()
+    if len(devices) < n:
+        where = what or f"mesh {tuple(shape)}"
+        platform = devices[0].platform
+        if platform == "cpu":
+            raise RuntimeError(
+                f"{where} needs {n} devices but cpu has {len(devices)}; the "
+                f"backend initialized before the mesh request — launch with "
+                f"XLA_FLAGS='{_FORCE_FLAG}={n}' in the environment")
+        raise RuntimeError(f"{where} needs {n} devices, {platform} has "
+                           f"{len(devices)}")
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_sim_mesh(spec):
-    """Mesh from a CLI spec ('4,2') over forced CPU host devices."""
+    """Mesh from a CLI spec ('4,2') over the default backend's devices."""
     shape, axes = parse_mesh_spec(spec)
     return make_forced_mesh(shape, axes, what=f"mesh {spec}")

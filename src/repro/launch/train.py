@@ -26,11 +26,17 @@ Usage (expert-TP: EP and TP as *distinct* axes — each expert's d_ff sharded
   PYTHONPATH=src python -m repro.launch.train --arch mula-7b-a1b --scale smoke \
       --parallel dp=2,ep=2,tp=2 --steps 10
 
+Usage (published widths, depth cut to one layer — the 50,304-token vocab
+is kept; byte-tokenizer ids are valid ids in it):
+  python -m repro.launch.train --arch mula-7b-a1b --scale full --layers 1 \
+      --batch 2 --seq 2048 --steps 5
+
 The legacy ``--mesh dp[,pp][,model]`` spec still works: it is translated to
 a ParallelPlan via ``ParallelPlan.from_legacy`` (the old role inference on
 the 'model' axis — EP when the expert count divides it, TP otherwise).
-Both paths force the plan's device product as CPU host devices through
-XLA_FLAGS when the backend allows it (see launch/mesh, parallel/plan).
+Both paths build the mesh over the default backend's devices; on the CPU
+platform the plan's device product is requested as host devices through
+XLA_FLAGS (see launch/mesh, parallel/plan).
 """
 from __future__ import annotations
 
@@ -100,7 +106,7 @@ def run(arch: str, *, scale: str = "smoke", steps: int = 100, batch: int = 8,
         seq: int = 128, out: str = "runs/default", lr: float = 1e-3,
         moe_impl: str = None, fur: bool = False, ckpt_interval: int = 50,
         microbatches: int = 1, sac: str = "block", seed: int = 0,
-        log_every: int = 10, d_model: int = 256, layers: int = 2,
+        log_every: int = 10, d_model: int = 256, layers: int = None,
         d_ff: int = 0, moe_dff: int = 0, mesh: str = None,
         parallel: str = None,
         opt_shard: str = None, opt_overlap: str = None,
@@ -123,14 +129,16 @@ def run(arch: str, *, scale: str = "smoke", steps: int = 100, batch: int = 8,
                          "(--mesh is the legacy spelling of --parallel)")
     os.makedirs(out, exist_ok=True)
 
-    # cfg is pure python — build it before the plan resolves (the resolve
-    # forces host devices, which must precede JAX backend initialization)
+    # cfg is pure python — build it before the plan resolves (on the CPU
+    # platform the resolve requests host devices, which must precede JAX
+    # backend initialization)
     cfg = get_config(arch)
     if scale == "smoke":
-        cfg = reduced(cfg, layers=layers, d_model=d_model,
+        cfg = reduced(cfg, layers=layers or 2, d_model=d_model,
                       vocab=ByteTokenizer.VOCAB)
-    else:
-        cfg = dataclasses.replace(cfg, vocab_size=ByteTokenizer.VOCAB)
+    elif layers:
+        # published widths and vocabulary; --layers cuts depth only
+        cfg = dataclasses.replace(cfg, num_layers=layers)
     if d_ff:
         cfg = dataclasses.replace(cfg, d_ff=d_ff)
     if cfg.moe is not None and (moe_impl or fur or moe_dff):
@@ -212,7 +220,7 @@ def run(arch: str, *, scale: str = "smoke", steps: int = 100, batch: int = 8,
         else (pp_schedule or "1f1b")
     pp_impl = pplan.pp_impl if pplan is not None else (pp_impl or "shardmap")
 
-    # resolve once: builds the mesh (forcing host devices first) + rules
+    # resolve once: builds the mesh (host devices requested first on CPU)
     plan = pplan.resolve(cfg, global_batch=batch) if pplan is not None \
         else None
     rules = plan.rules if plan is not None else None
@@ -490,20 +498,21 @@ def main():
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--sac", default="block")
     ap.add_argument("--d-model", type=int, default=256)
-    ap.add_argument("--layers", type=int, default=2)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="depth: smoke default 2; with --scale full, cuts "
+                         "the published depth (widths and vocab unchanged)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt-interval", type=int, default=50)
     ap.add_argument("--parallel", default=None,
                     help="declarative ParallelPlan spec, e.g. "
                          "'dp=2,pp=2,ep=2' or 'dp=2,ep=2,tp=2' (expert-TP); "
                          "axes: dp, pp, ep, tp, pod; options: opt=, "
-                         "schedule=, moe=, tiles=, mb=, fsdp. Forces the "
-                         "device "
-                         "product "
-                         "as CPU host devices; pp>1 enables the jitted "
-                         "pipeline schedule")
+                         "schedule=, moe=, tiles=, mb=, fsdp. The mesh "
+                         "spans the default backend's devices (on CPU the "
+                         "device product is requested as host devices); "
+                         "pp>1 enables the jitted pipeline schedule")
     ap.add_argument("--mesh", default=None,
-                    help="LEGACY simulated device mesh: '4,2' = (data, "
+                    help="LEGACY device mesh: '4,2' = (data, "
                          "model), '2,2,2' = (data, pp, model); translated "
                          "to a ParallelPlan (MoE: model axis -> ep when "
                          "divisible, else tp). Prefer --parallel")
@@ -574,6 +583,8 @@ def main():
                     help="inject one soft (NaN) failure at this step "
                          "(also REPRO_INJECT_SOFT_AT)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(args.arch, scale=args.scale, steps=args.steps, batch=args.batch,
         seq=args.seq, out=args.out, lr=args.lr, moe_impl=args.moe_impl,
         fur=args.fur, microbatches=args.microbatches, sac=args.sac,

@@ -137,9 +137,9 @@ def _blockwise_attention(q, k, v, *, causal: bool, window: int,
     neg = jnp.float32(-1e30)
 
     # NOTE: both block scans walk a *carried* int32 counter instead of
-    # scanning over a jnp.arange xs: an iota-valued scan operand trips the
-    # SPMD partitioner inside partial-auto shard_map regions (the
-    # per-stage pipeline executor) on jax 0.4.x — "Check failed:
+    # scanning over a jnp.arange xs: an iota-valued scan operand has
+    # check-failed the SPMD partitioner inside partial-auto shard_map
+    # regions (the per-stage pipeline executor) — "Check failed:
     # sharding.IsManualSubgroup()". A carried counter is bit-identical.
     def q_step(qi, _):
         qt = qr[:, :, :, qi].astype(jnp.float32) * scale   # (B,nkv,g,qb,hd)

@@ -56,7 +56,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import manual_shard_map
 from repro.optim.adamw import AdamWState, adamw_leaf
 from repro.optim.epso import (DEFAULT_BUCKET_BYTES, UpdatePlan, _entry_axes,
                               optimizer_state_specs, plan_update_buckets,
@@ -277,10 +276,14 @@ def overlapped_adamw_update(grads, state: AdamWState, *, rules, mode: str,
     scal_specs = (P(), P(), P(), P())
     # grads enter under the STATE specs: GSPMD lowers the mismatch against
     # the backward's partial sums to a reduce-scatter (the paper's grad RS)
-    fn = manual_shard_map(
-        region, mesh,
+    # check_vma=False: the ring all-gather's outputs are replicated by
+    # construction, but ppermute chains cannot be typed invariant; the
+    # overlapped-vs-eager goldens in tests/test_opt_overlap.py are the check
+    fn = jax.shard_map(
+        region, mesh=mesh,
         in_specs=(ospecs, ospecs, ospecs, ospecs, scal_specs),
-        out_specs=(pspecs, ospecs, ospecs, ospecs, P(), P()))
+        out_specs=(pspecs, ospecs, ospecs, ospecs, P(), P()),
+        check_vma=False)
     clip_arg = jnp.asarray(True if clip_enabled is None else clip_enabled)
     scalars = (jnp.asarray(lr, jnp.float32),
                jnp.asarray(bc1, jnp.float32),

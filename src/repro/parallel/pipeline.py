@@ -547,8 +547,18 @@ def pipelined_loss_and_grads_per_stage(embed_fn, block_fn, head_fn,
     # PartitionId op this XLA's SPMD partitioner rejects. Region bodies are
     # scan-free on purpose — iota-xs scans and scan-under-cond patterns
     # check-fail the partial-auto partitioner (see models/layers.py note).
+    # The replicated io params are cast to 'pp'-varying at region entry,
+    # outside the cond: the transpose of that cast is the psum over 'pp'
+    # that sums io cotangents across stages. Left to the branches, the
+    # implicit cast (and so its psum) lands inside the cond, where only
+    # one stage runs it, and the collective deadlocks.
+    def _varying_io(io):
+        return jax.tree.map(lambda a: jax.lax.pcast(a, "pp", to="varying"),
+                            io)
+
     def _in_region(sid_blk, io, x_blk, mb_blk):
         sid = sid_blk[0]
+        io = _varying_io(io)
         mb1 = jax.tree.map(lambda a: a[0], mb_blk)
         h = jax.lax.cond(
             sid == 0,
@@ -558,11 +568,14 @@ def pipelined_loss_and_grads_per_stage(embed_fn, block_fn, head_fn,
 
     def _head_region(sid_blk, io, y_blk, mb_blk):
         sid = sid_blk[0]
+        io = _varying_io(io)
         mb1 = jax.tree.map(lambda a: a[0], mb_blk)
+        # both branches vary over 'pp' (check_vma needs matching types):
+        # the head reads this stage's activation, the zero is cast to match
         ce = jax.lax.cond(
             sid == pp - 1,
             lambda: head_fn(io, y_blk[0], mb1).astype(f32),
-            lambda: jnp.zeros((), f32))
+            lambda: jax.lax.pcast(jnp.zeros((), f32), "pp", to="varying"))
         return ce[None]
 
     stage_in = jax.shard_map(

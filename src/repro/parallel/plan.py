@@ -505,11 +505,12 @@ class ParallelPlan:
 
         Token/batch rows shard over (pod, data[, ep]) — EP gathers tokens
         over its own axis exactly as the legacy 'ep' role did over 'model'.
-        ``devices`` overrides the device pool (tests); by default the CPU
-        backend is asked for ``num_devices`` host devices (only effective
-        before backend init — same contract as ``launch.mesh``)."""
+        ``devices`` overrides the device pool (tests); by default the mesh
+        spans the default backend's devices (``launch.mesh.make_forced_mesh``:
+        on the CPU platform it asks for ``num_devices`` host devices, which
+        only takes effect before backend init)."""
         import jax
-        from repro.compat import AxisType
+        from jax.sharding import AxisType
         from repro.parallel.sharding import (ShardingRules, ep_batch_axes,
                                              resolve_batch_axes)
 

@@ -11,20 +11,6 @@ for _p in (_SRC, _HERE):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-# old-jax shims (jax.sharding.AxisType / AbstractMesh signature / make_mesh
-# axis_types kwarg) — a no-op on modern jax.
-import repro.compat  # noqa: E402,F401
-
-# the suite's property tests use hypothesis; fall back to the deterministic
-# sampler stub when the real package isn't installed.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import _hypothesis_stub as _h
-
-    sys.modules.setdefault("hypothesis", _h)
-    sys.modules.setdefault("hypothesis.strategies", _h.strategies)
-
 import jax  # noqa: E402
 import pytest  # noqa: E402
 

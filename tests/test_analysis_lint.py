@@ -61,9 +61,12 @@ def test_sl001_raw_shard_map(src):
     assert _rules(L.lint_source(src, "synthetic/mod.py")) == ["SL001"]
 
 
-def test_sl001_allowlisted_in_compat():
+def test_sl001_fires_in_compat():
+    """SL001 has no allowlist: the path that once owned the old-jax shims
+    is held to the rule like any other."""
+    assert L.ALLOWLIST["SL001"] == ()
     src = "from jax.experimental.shard_map import shard_map"
-    assert L.lint_source(src, "src/repro/compat.py") == []
+    assert _rules(L.lint_source(src, "src/repro/compat.py")) == ["SL001"]
 
 
 def test_sl001_cli_exits_nonzero(tmp_path):

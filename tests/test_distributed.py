@@ -19,6 +19,25 @@ def test_parse_mesh_spec():
         parse_mesh_spec("")
 
 
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_forced_mesh_too_few_devices_names_platform(monkeypatch, platform):
+    """A plan that needs more devices than the backend has fails with both
+    counts; only on the CPU does the message point at the host-device flag
+    (an accelerator has the chips it has)."""
+    import types
+    from repro.launch import mesh as M
+    monkeypatch.setenv("XLA_FLAGS", "")
+    monkeypatch.setattr(M.jax, "devices",
+                        lambda: [types.SimpleNamespace(platform=platform)])
+    with pytest.raises(RuntimeError) as e:
+        M.make_forced_mesh((2, 2), ("data", "model"), what="plan ep=4")
+    msg = str(e.value)
+    assert msg.startswith("plan ep=4 needs 4 devices")
+    assert (M._FORCE_FLAG in msg) == (platform == "cpu")
+    if platform == "tpu":
+        assert msg == "plan ep=4 needs 4 devices, tpu has 1"
+
+
 @pytest.mark.slow
 def test_fsmoe_ep_matches_naive_with_grads(mesh8):
     """Paper Algorithm 1 under a real 2x4 (data, model) mesh: forward and
@@ -28,7 +47,7 @@ def test_fsmoe_ep_matches_naive_with_grads(mesh8):
     out = mesh8("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType
+        from jax.sharding import AxisType
         from repro.configs.base import ModelConfig, MoEConfig
         from repro.core import moe as M
         mesh = jax.make_mesh((2, 4), ("data", "model"),
@@ -71,7 +90,7 @@ def test_fsmoe_a2a_dispatch_matches_naive(mesh8):
     out = mesh8("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType
+        from jax.sharding import AxisType
         from repro.configs.base import ModelConfig, MoEConfig
         from repro.core import moe as M
         mesh = jax.make_mesh((2, 4), ("data", "model"),
@@ -112,7 +131,7 @@ def test_moe_etp_shard_map_matches_naive(mesh8):
     out = mesh8("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType
+        from jax.sharding import AxisType
         from repro.configs.base import ModelConfig, MoEConfig
         from repro.core import moe as M
         mesh = jax.make_mesh((2, 4), ("data", "model"),
@@ -197,7 +216,7 @@ def test_epso_state_placement_on_devices(mesh8):
     """EPSO states occupy fewer bytes per device than SO on a real mesh."""
     out = mesh8("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import AxisType
+        from jax.sharding import AxisType
         from repro.configs import get_config, reduced
         from repro.models import init_params
         from repro.optim import adamw_init

@@ -8,9 +8,6 @@ naive math for ANY routing, independent of pool-geometry knobs like
 ``c_align`` — which is what makes pp=1 and pp>1 losses comparable at
 shapes where the capacity path's different pool geometries diverge
 (the c_align parity test at the bottom pins that).
-
-Property tests run on the hypothesis stub when hypothesis isn't installed
-(tests/_hypothesis_stub.py — deterministic sampling, same @given API).
 """
 import dataclasses
 
@@ -292,7 +289,7 @@ def test_dropless_ep_tp_matches_naive_mesh8(mesh8):
     out = mesh8("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType
+        from jax.sharding import AxisType
         from repro.configs.base import ModelConfig, MoEConfig
         from repro.core import moe as M
         mesh = jax.make_mesh((2, 2, 2), ("data", "ep", "tp"),

@@ -366,7 +366,7 @@ def test_ep_tp_axis_pair_through_sparse_moe_block(mesh8):
     out = mesh8("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import AxisType
+        from jax.sharding import AxisType
         from repro.configs.base import ModelConfig, MoEConfig
         from repro.core import moe as M
         mesh = jax.make_mesh((2, 2, 2), ("data", "ep", "tp"),

@@ -179,8 +179,14 @@ def test_placed_train_step_bit_identical_and_counts_conserved():
     """Permute the expert stacks (params AND optimizer state) to a
     non-identity placement, train with the placement threaded through the
     plan: losses and the global-id ``moe_counts`` telemetry are bit-equal
-    to the identity run, and un-permuting the trained stacks recovers the
-    identity run's params bitwise (top_k=2: see placement.py docstring)."""
+    to the identity run (top_k=2: see placement.py docstring), and
+    un-permuting the trained stacks recovers the identity run's params and
+    optimizer state to within 1e-5 of each leaf's largest magnitude. Not
+    bitwise: the placed step is a different program (it gathers through
+    the placement row), and on jax 0.9 XLA's CPU backend fuses its backward
+    reductions (norm-scale and embedding gradients) in another order, which
+    moves the gradients by an ulp; after four steps the largest drift is
+    ~3e-7 of a leaf's scale. A wrong expert mapping moves whole slices."""
     import jax
     import jax.numpy as jnp
     from repro.configs import (ParallelConfig, TrainConfig, get_config,
@@ -240,10 +246,17 @@ def test_placed_train_step_bit_identical_and_counts_conserved():
             jax.tree_util.tree_leaves_with_path(sa.params),
             jax.tree_util.tree_leaves_with_path(sb_back.params)):
         assert jax.tree_util.keystr(pa) == jax.tree_util.keystr(pb)
-        assert np.array_equal(np.asarray(a), np.asarray(b)), pa
+        assert _ulp_close(a, b), pa
     for a, b in zip(jax.tree_util.tree_leaves(sa.opt),
                     jax.tree_util.tree_leaves(sb_back.opt)):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
+        assert _ulp_close(a, b)
+
+
+def _ulp_close(a, b, rel=1e-5):
+    """Largest |a - b| within ``rel`` of the leaf's largest |a|."""
+    a = np.asarray(a, np.float64)
+    b = np.asarray(b, np.float64)
+    return float(np.abs(a - b).max()) <= rel * float(np.abs(a).max())
 
 
 def test_update_plan_invariant_under_placement():
@@ -251,7 +264,7 @@ def test_update_plan_invariant_under_placement():
     reads only shapes and specs) — pin it: the plan computed from permuted
     shapes is identical."""
     import jax
-    from repro.compat import AxisType
+    from jax.sharding import AxisType
     from jax.sharding import AbstractMesh
     from repro.configs import get_config, reduced
     from repro.models import init_params

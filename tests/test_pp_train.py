@@ -37,7 +37,11 @@ def _batch(cfg, batch=8, seq=16, seed=1):
 @pytest.mark.parametrize("sched", ["1f1b", "gpipe"])
 def test_pp_step_bit_matches_non_pp_single_device(arch, at, sched):
     """pp_stages=2 through the jitted executor == the plain microbatch-
-    accumulation step, bit-for-bit (single device: identical op order)."""
+    accumulation step (single device: identical op order). Params and ce
+    are bit-for-bit. The MoE loss scalar is held to 2 f32 ulp instead: the
+    executor combines ce + (aux, z) from sums over microbatches, the plain
+    step per microbatch, and f32 addition does not associate (they differ
+    by 1 ulp on jax 0.9). The dense loss has no aux terms and stays exact."""
     cfg = reduced(get_config(arch), layers=2, d_model=32)
     assert cfg.arch_type == at
     tc = _tc()
@@ -48,7 +52,9 @@ def test_pp_step_bit_matches_non_pp_single_device(arch, at, sched):
     s_pp, m_pp = jax.jit(make_train_step(
         cfg, ParallelConfig(microbatches=4, pp_stages=2, pp_schedule=sched),
         tc))(state, batch)
-    assert float(m_ref["loss"]) == float(m_pp["loss"])
+    loss_ref = np.float32(m_ref["loss"])
+    ulp = 2 * np.spacing(loss_ref) if at == "moe" else 0.0
+    assert abs(loss_ref - np.float32(m_pp["loss"])) <= ulp, (m_ref, m_pp)
     assert float(m_ref["ce"]) == float(m_pp["ce"])
     for a, b in zip(jax.tree.leaves(s_ref.params), jax.tree.leaves(s_pp.params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -76,7 +82,7 @@ def test_pp1_falls_back_to_plain_step():
 def test_pp_shardmap_rejects_indivisible_microbatches():
     """The per-stage executor's wave-balance guardrail surfaces at build
     time with a descriptive error (mesh is shape-only — no devices)."""
-    from repro.compat import AxisType
+    from jax.sharding import AxisType
     from jax.sharding import AbstractMesh
 
     cfg = reduced(get_config("mula-1b"), layers=2, d_model=32)
