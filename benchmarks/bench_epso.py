@@ -36,9 +36,7 @@ if os.path.join(ROOT, "src") not in sys.path:      # direct-script invocation
 
 import jax
 import numpy as np
-
-from jax.sharding import AxisType
-from jax.sharding import AbstractMesh
+from jax.sharding import AbstractMesh, AxisType
 
 from repro.configs import get_config
 from repro.models import init_params
