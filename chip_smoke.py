@@ -17,15 +17,18 @@ params and compute over an fp32 master), dropless dispatch, and
    backend against the ``naive`` reference in float32 (output and every
    gradient), plus the token-count and flash-attention kernels against
    their references;
-2. train: compile the step once, then ``--steps`` steps on one repeated
-   batch of token ids drawn from ``--seed`` over the whole vocabulary. The
-   loss must stay finite and fall, and the compiled step must contain the
-   Pallas kernels (``tpu_custom_call``).
+2. train: compile the step once, then 6 steps on one repeated batch of
+   token ids drawn from ``--seed`` over the whole vocabulary. The loss must
+   stay finite and fall, and the compiled step must contain the Pallas
+   kernels (``tpu_custom_call``).
 
-Four chips (``--four-chips``, that phase only): the same model under
-``ep=4,opt=epso,moe=dropless`` for 3 steps, against the same seed, batch
-and steps on one device. Dropless EP computes the single-device math, so
-the losses agree to bf16 rounding.
+Four chips (``--four-chips``, that phase only): the same model, with the
+load-balance loss off, under ``ep=4,opt=epso,moe=dropless`` for 3 steps,
+against the same seed, batch and steps on one device, in the paper's
+dtypes and again in float32 (params, compute, gradient reduction and XLA
+matmuls). Dropless EP computes the single-device math, so every loss and
+every grad norm agree, to bf16 rounding and to float32 accumulation order:
+the check of the EP forward, backward and EPSO update together.
 
 Every line but the last is progress. The last line, printed only when every
 phase passed, is one JSON object: ``{"ok": true, "device": {...}}``. With no
@@ -47,6 +50,9 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 SEQ = 2048
 LAYERS = 1
 BATCH = 4            # from the step's memory_analysis at 1 layer (PERF.md)
+TRAIN_STEPS = 6
+EP_STEPS = 3
+F32_SEQ = 512        # float32 state is 10 GB: shorter sequences to fit one chip
 CHECK_TOKENS = 512   # tokens in the full-width MoE layer check
 # max |pallas - naive| <= MOE_TOL * max |naive|, per tensor, both sides at
 # float32 "highest" matmul precision. A routing or grouping error moves
@@ -54,14 +60,11 @@ CHECK_TOKENS = 512   # tokens in the full-width MoE layer check
 # ~4e-3; what is left at f32 is accumulation order.
 MOE_TOL = 1e-3
 FLASH_TOL = 2e-2     # bf16 output (2**-8) over an f32 reference
-# four chips vs one, bf16 train steps: every step's loss, and the first
-# step's grad norm, within one bf16 ulp (2**-8 relative). Later grad norms
-# are printed, not compared: Adam's first update moves each weight by about
-# +-lr whatever the size of its gradient, so bf16 rounding noise in a
-# near-zero gradient flips that weight's step, and the grad norms of the
-# two runs drift apart (7% at step 2 on a v5e) while their losses stay
-# within the bound. The float32 EP layer check is the sharp test.
+# four chips vs one, every step's loss and grad norm, relative: one bf16
+# ulp with the paper's dtypes (measured on a v5e: 5.4e-4 at most); 2**-16
+# in float32, where what is left is accumulation order (2.7e-7 at most)
 EP_RTOL = 2.0 ** -8
+EP_F32_RTOL = 2.0 ** -16
 
 
 def log(msg: str) -> None:
@@ -73,12 +76,16 @@ def model_config(layers: int = LAYERS):
     return dataclasses.replace(get_config("mula-7b-a1b"), num_layers=layers)
 
 
-def train_config(batch: int, seed: int, seq: int = SEQ):
+def train_config(seed: int, seq: int = SEQ, dtype: str = "bfloat16"):
+    """The paper's recipe; ``dtype="bfloat16"`` gives its dtypes (bf16
+    params, compute and gradient reduction over the fp32 master), float32
+    puts all three in float32. Warmup 2, so that step 1 already moves the
+    weights (the schedule gives lr 0 at step 0)."""
     from repro.configs import TrainConfig
-    # the paper's recipe and dtypes; warmup 2 so that step 1 already moves
-    # the weights (the schedule gives lr 0 at step 0)
-    return TrainConfig(seq_len=seq, global_batch=batch, warmup_steps=2,
-                       total_steps=100, param_dtype="bfloat16", seed=seed)
+    dt = {} if dtype == "bfloat16" else dict(compute_dtype=dtype,
+                                             grad_reduce_dtype=dtype)
+    return TrainConfig(seq_len=seq, global_batch=BATCH, warmup_steps=2,
+                       total_steps=100, param_dtype=dtype, seed=seed, **dt)
 
 
 def kernel_plan():
@@ -222,12 +229,12 @@ def run_steps(step, state, batch, steps: int, tag: str):
     return state, losses, gnorms
 
 
-def train_one_chip(cfg, batch_size: int, steps: int, seed: int) -> None:
+def train_one_chip(cfg, seed: int) -> None:
     import jax
     from repro.parallel.plan import ParallelPlan
     from repro.train import init_state
 
-    train = train_config(batch_size, seed)
+    train = train_config(seed)
     plan = ParallelPlan(moe_dispatch="dropless",
                         kernel=kernel_plan()).resolve(cfg, train)
     t = time.perf_counter()
@@ -237,9 +244,9 @@ def train_one_chip(cfg, batch_size: int, steps: int, seed: int) -> None:
     log(f"train: {cfg.name} layers={cfg.num_layers} d_model={cfg.d_model} "
         f"experts={cfg.moe.num_experts} top_k={cfg.moe.experts_per_token} "
         f"d_ff_expert={cfg.moe.d_ff_expert} vocab={cfg.vocab_size} "
-        f"params={n} batch={batch_size}x{SEQ} plan='{plan.spec()}' "
+        f"params={n} batch={BATCH}x{SEQ} plan='{plan.spec()}' "
         f"init {time.perf_counter() - t:.2f}s")
-    batch = jax.device_put(make_batch(cfg, batch_size, SEQ, seed))
+    batch = jax.device_put(make_batch(cfg, BATCH, SEQ, seed))
     step = build_step(cfg, train, plan)
     t = time.perf_counter()
     compiled = step.lower(state, batch).compile()
@@ -249,7 +256,8 @@ def train_one_chip(cfg, batch_size: int, steps: int, seed: int) -> None:
     log(f"train: compiled step contains tpu_custom_call: {has_kernel}")
     if not has_kernel:
         raise SystemExit("the compiled step holds no Pallas kernel")
-    state, losses, _ = run_steps(compiled, state, batch, steps, "train")
+    state, losses, _ = run_steps(compiled, state, batch, TRAIN_STEPS,
+                                 "train")
     stats = jax.devices()[0].memory_stats() or {}
     log(f"train: peak_bytes_in_use {stats.get('peak_bytes_in_use')}")
     if not all(math.isfinite(v) for v in losses):
@@ -263,31 +271,42 @@ def train_one_chip(cfg, batch_size: int, steps: int, seed: int) -> None:
 # four chips: EP=4 dropless + EPSO against one device
 # ----------------------------------------------------------------------------
 
-def four_chips(cfg, batch_size: int, steps: int, seed: int) -> None:
+def ep_vs_one_device(cfg, seed: int, dtype: str):
+    """EP=4 + EPSO and one device, same seed, batch and steps. Returns
+    {"one-device"|"ep=4": (losses, grad_norms)} and the EP mesh."""
+    import contextlib
     import jax
     from repro.parallel.plan import ParallelPlan
     from repro.parallel.sharding import batch_sharding
     from repro.train import init_state
 
-    train = train_config(batch_size, seed)
-    host_batch = make_batch(cfg, batch_size, SEQ, seed)
-    runs = {}
-    for tag, pplan in (
+    seq = SEQ if dtype == "bfloat16" else F32_SEQ
+    train = train_config(seed, seq, dtype)
+    host_batch = make_batch(cfg, BATCH, seq, seed)
+    # float32 XLA matmuls run at full precision, not one bf16 pass
+    precision = (contextlib.nullcontext() if dtype == "bfloat16"
+                 else jax.default_matmul_precision("highest"))
+    runs, mesh = {}, None
+    for name, pplan in (
             ("one-device", ParallelPlan(moe_dispatch="dropless",
                                         kernel=kernel_plan())),
             ("ep=4", ParallelPlan(ep=4, opt_shard="epso",
                                   moe_dispatch="dropless",
                                   kernel=kernel_plan()))):
+        tag = f"{name} {dtype}"
         plan = pplan.resolve(cfg, train)
+        mesh = plan.mesh or mesh
         state = init_state(jax.random.PRNGKey(seed), cfg, train, plan=plan)
         bsh = batch_sharding(plan.rules)
         batch = jax.device_put(host_batch, bsh) if bsh is not None \
             else jax.device_put(host_batch)
         t = time.perf_counter()
-        compiled = build_step(cfg, train, plan).lower(state, batch).compile()
-        log(f"{tag}: plan '{plan.spec()}' compile "
+        with precision:
+            compiled = build_step(cfg, train, plan).lower(state,
+                                                          batch).compile()
+        log(f"{tag}: plan '{plan.spec()}' batch {BATCH}x{seq} compile "
             f"{time.perf_counter() - t:.2f}s")
-        if plan.mesh is not None:
+        if plan.mesh is not None and dtype == "bfloat16":
             gate = state.params["layers"]["moe"]["gate"]
             for sh in sorted(gate.addressable_shards,
                              key=lambda s: s.device.id):
@@ -301,20 +320,35 @@ def four_chips(cfg, batch_size: int, steps: int, seed: int) -> None:
                         + sh.data.nbytes
             for d in sorted(per_dev):
                 log(f"{tag}: device {d} EPSO state bytes {per_dev[d]}")
-        state, losses, gnorms = run_steps(compiled, state, batch, steps, tag)
-        runs[tag] = (losses, gnorms)
+        state, losses, gnorms = run_steps(compiled, state, batch, EP_STEPS,
+                                          tag)
+        runs[name] = (losses, gnorms)
         del state, compiled
-        if plan.mesh is not None:
-            check_moe_layer(cfg, seed, tag, mesh=plan.mesh)
-    (l1, g1), (le, ge) = runs["one-device"], runs["ep=4"]
-    dl = max(abs(a - b) / abs(b) for a, b in zip(le, l1))
-    dg = abs(ge[0] - g1[0]) / abs(g1[0])
-    log(f"ep=4 vs one-device: max loss rel diff={dl:.3e}, step-0 grad-norm "
-        f"rel diff={dg:.3e} (tolerance {EP_RTOL:g})")
-    if not all(math.isfinite(v) for v in le + l1):
-        raise SystemExit("non-finite loss")
-    if not (dl <= EP_RTOL and dg <= EP_RTOL):
-        raise SystemExit("EP=4 does not agree with the one-device run")
+    return runs, mesh
+
+
+def four_chips(cfg, seed: int) -> None:
+    # EP computes the load-balance loss over each rank's tokens and averages
+    # it over the ranks (Switch-style); one device computes it over the
+    # whole batch. The two are different objectives (at step 2 the grad
+    # norms differ by 7% on a v5e), so both runs turn it off
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, router_aux_coef=0.0))
+    for dtype, rtol in (("bfloat16", EP_RTOL), ("float32", EP_F32_RTOL)):
+        runs, mesh = ep_vs_one_device(cfg, seed, dtype)
+        (l1, g1), (le, ge) = runs["one-device"], runs["ep=4"]
+        dl = [abs(a - b) / abs(b) for a, b in zip(le, l1)]
+        dg = [abs(a - b) / abs(b) for a, b in zip(ge, g1)]
+        log(f"ep=4 vs one-device, {dtype}: loss rel diff per step "
+            + " ".join(f"{v:.3e}" for v in dl) + ", grad-norm rel diff "
+            "per step " + " ".join(f"{v:.3e}" for v in dg)
+            + f" (tolerance {rtol:g})")
+        if not all(math.isfinite(v) for v in le + l1 + ge + g1):
+            raise SystemExit(f"non-finite loss or grad norm ({dtype})")
+        if not max(dl + dg) <= rtol:
+            raise SystemExit(f"EP=4 does not agree with the one-device run "
+                             f"({dtype})")
+    check_moe_layer(cfg, seed, "ep=4", mesh=mesh)
 
 
 def main(argv=None) -> int:
@@ -322,10 +356,6 @@ def main(argv=None) -> int:
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the EP=4 phase (needs four chips)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--steps", type=int, default=None,
-                    help="train steps (default 6; 3 with --four-chips)")
-    ap.add_argument("--batch", type=int, default=BATCH,
-                    help=f"sequences of {SEQ} tokens per step")
     args = ap.parse_args(argv)
 
     import jax
@@ -345,10 +375,10 @@ def main(argv=None) -> int:
         f"{cache_dir}")
     cfg = model_config()
     if args.four_chips:
-        four_chips(cfg, args.batch, args.steps or 3, args.seed)
+        four_chips(cfg, args.seed)
     else:
         check_kernels(cfg, args.seed)
-        train_one_chip(cfg, args.batch, args.steps or 6, args.seed)
+        train_one_chip(cfg, args.seed)
     print(json.dumps({"ok": True, "device": {
         "platform": platform, "kind": devices[0].device_kind,
         "count": len(devices)}}))

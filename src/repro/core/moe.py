@@ -542,18 +542,16 @@ def moe_fsmoe_ep(p, x, moe_cfg, *, mesh, ep_axis: str = "model",
         operands.append(jnp.asarray(placement, jnp.int32))
         in_specs.append(P(None))
     # The replicated outputs type-check: counts and drops are psum'd over
-    # ep, aux and z pmean'd over every axis. Only Pallas's CPU interpreter
-    # cannot evaluate a kernel whose scalar-prefetched tile->group map
-    # varies over ep under check_vma (jax 0.9 raises in its index-map
-    # evaluation), so interpret-mode runs turn the check off; there the EP
-    # goldens against the naive MoE (tests/test_distributed.py) are the check.
+    # ep, aux and z pmean'd over every axis. Interpret-mode Pallas kernels
+    # cannot run under the check (ops.vma_checkable); there the EP goldens
+    # against the naive MoE (tests/test_distributed.py) are the check.
     from repro.kernels import ops
-    interpreted = stage45_backend(moe_cfg) == "pallas" and ops._interpret()
+    check = stage45_backend(moe_cfg) != "pallas" or ops.vma_checkable()
     out, aux, z, stats = jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(token_spec, P(), P(), MoeStats(P(None), P())),
-        axis_names=manual, check_vma=not interpreted)(*operands)
+        axis_names=manual, check_vma=check)(*operands)
     out = checkpoint_name(out, "moe_out")
     if moe_cfg.num_shared_experts:
         out = out + _shared_expert(p, x)

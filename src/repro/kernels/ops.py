@@ -41,7 +41,8 @@ from .moe_dispatch import token_counts_pallas
 __all__ = ["KernelPlan", "current_kernel_plan", "default_kernel_plan",
            "set_default_kernel_plan", "use_kernel_plan",
            "gmm", "combine", "fused_swiglu", "token_counts",
-           "flash_attention", "gmm_align", "ssd_intra_chunk"]
+           "flash_attention", "gmm_align", "ssd_intra_chunk",
+           "vma_checkable"]
 
 
 def _interpret() -> bool:
@@ -49,6 +50,15 @@ def _interpret() -> bool:
     if flag is None:
         return jax.default_backend() == "cpu"
     return bool(flag)
+
+
+def vma_checkable() -> bool:
+    """Whether the Pallas kernels may run inside a ``check_vma`` shard_map
+    region. Compiled kernels may. Pallas's CPU interpreter cannot evaluate
+    a kernel whose scalar-prefetched tile->group index map varies over a
+    manual axis (jax 0.9 raises in its index-map evaluation), so interpret
+    mode may not: callers turn the check off there."""
+    return not _interpret()
 
 
 def gmm_align() -> int:

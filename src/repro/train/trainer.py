@@ -104,7 +104,10 @@ def init_state(rng, cfg: ModelConfig, train: TrainConfig, *,
     params = init_params(rng, cfg)
     opt = adamw_init(params)
     pd = jnp.dtype(train.param_dtype)
-    params = jax.tree.map(lambda p: p.astype(pd), params)
+    # a copy where pd is the master's float32: params and master that share
+    # a buffer could not be donated to the step
+    params = jax.tree.map(
+        lambda p: p.astype(pd) if p.dtype != pd else jnp.copy(p), params)
     state = TrainState(params, opt)
     sh = train_state_shardings(params, rules, opt_sharding_mode)
     if sh is not None:

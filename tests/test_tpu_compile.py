@@ -21,17 +21,16 @@ T, SEQ = 2048, 2048          # routed tokens per call; attention sequence
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     with pytest.MonkeyPatch.context() as mp:
         if "TPU_LOG_DIR" not in os.environ:      # else libtpu logs to /tmp
             mp.setenv("TPU_LOG_DIR", "disabled")
         try:
-            topo = topologies.get_topology_desc(platform="tpu",
+            desc = topologies.get_topology_desc(platform="tpu",
                                                 topology_name="v5e:2x2")
         except Exception as e:  # noqa: BLE001 — any failure means no TPU lib
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
@@ -41,10 +40,24 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield desc
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_plan():
+    # the ops wrappers pick interpret mode from the backend, which is the
+    # CPU here: the plan forces the compiled (Mosaic) lowering
+    from repro.parallel.plan import KernelPlan
+    return KernelPlan(backend="pallas", interpret=False, tile_m=128,
+                      tile_k=512, tile_n=512)
 
 
 def _widths():
@@ -103,15 +116,49 @@ def _cases():
                                   "token_counts", "flash_attention_fwd"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     import jax
-    from repro.parallel.plan import KernelPlan, use_kernel_plan
+    from repro.parallel.plan import use_kernel_plan
 
     fn, specs = _cases()[name]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in specs]
-    # the ops wrappers pick interpret mode from the backend, which is the
-    # CPU here: the plan forces the compiled (Mosaic) lowering
-    plan = KernelPlan(backend="pallas", interpret=False, tile_m=128,
-                      tile_k=512, tile_n=512)
-    with use_kernel_plan(plan):
+    with use_kernel_plan(_compiled_plan()):
         compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+def test_ep_moe_layer_compiles_for_v5e_2x2(topo):
+    """The dropless MoE layer under EP over the four described chips, its
+    Pallas kernels compiled inside the ``check_vma`` shard_map region
+    (``core/moe.py moe_fsmoe_ep``) — the combination interpret-mode runs
+    cannot type-check (``ops.vma_checkable``): forward and gradients."""
+    import dataclasses
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.configs import get_config
+    from repro.core.moe import init_moe_block, sparse_moe_block
+    from repro.parallel.plan import ParallelPlan, use_kernel_plan
+
+    cfg = get_config("mula-7b-a1b")
+    cfg = dataclasses.replace(cfg, num_layers=1, moe=dataclasses.replace(
+        cfg.moe, dispatch="dropless", moe_impl="fsmoe"))
+    mesh = ParallelPlan(ep=4).resolve(cfg, devices=topo.devices).mesh
+    params = jax.eval_shape(lambda: init_moe_block(jax.random.PRNGKey(0),
+                                                   cfg))
+    shard = {"gate": P("ep"), "up": P("ep"), "down": P("ep")}
+    params = {k: jax.ShapeDtypeStruct(v.shape, jnp.bfloat16,
+                                      sharding=NamedSharding(
+                                          mesh, shard.get(k, P())))
+              for k, v in params.items()}
+    x = jax.ShapeDtypeStruct((1, T, cfg.d_model), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P()))
+
+    def loss(p, xx):
+        out = sparse_moe_block(p, xx, cfg, mesh=mesh, ep_axis="ep",
+                               batch_axes=())[0]
+        return out.astype(jnp.float32).sum()
+
+    with use_kernel_plan(_compiled_plan()):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
