@@ -26,6 +26,7 @@ import time
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 # ---------------------------------------------------------------------------
@@ -260,7 +261,9 @@ class Checkpointer:
     def maybe_save(self, state, params, step: int):
         wrote = []
         if step > 0 and step % self.interval == 0:
-            wrote.append(self.save(state, step))
+            with TraceAnnotation("ckpt.save"):
+                wrote.append(self.save(state, step))
         if step > 0 and step % self.model_only_interval == 0:
-            wrote.append(self.save_model_only(params, step))
+            with TraceAnnotation("ckpt.save"):
+                wrote.append(self.save_model_only(params, step))
         return wrote

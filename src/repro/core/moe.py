@@ -53,6 +53,7 @@ Router weights and shared experts are never permuted.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import NamedTuple
 
@@ -151,11 +152,26 @@ class DispatchPlan(NamedTuple):
     drops: jax.Array         # scalar: number of dropped (over-capacity) pairs
 
 
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class PoolRows:
+    """Slot-pool rows the grouped matmul's grid covers, summed over the
+    chips that dispatch distinct tokens. Known from shapes at trace time, so
+    it is a static pytree node (no leaves): it crosses jit, remat, scan and
+    vjp as structure and never becomes a device value."""
+    n: int = 0
+
+    def __add__(self, other: "PoolRows") -> "PoolRows":
+        return PoolRows(self.n + other.n)
+
+
 class MoeStats(NamedTuple):
-    """Per-layer routing telemetry. float32 (not int) so it rides through
-    vjp/scan/psum alongside the loss scalars with zero cotangents."""
+    """Per-layer routing telemetry. counts/drops are float32 (not int) so
+    they ride through vjp/scan/psum alongside the loss scalars with zero
+    cotangents; ``rows`` is static (``PoolRows``)."""
     counts: jax.Array        # (E,) routed (t, k) pairs per global expert
     drops: jax.Array         # () pairs dropped over capacity (0 when dropless)
+    rows: PoolRows = PoolRows()
 
     @classmethod
     def zero(cls, num_experts: int) -> "MoeStats":
@@ -163,7 +179,8 @@ class MoeStats(NamedTuple):
                    jnp.zeros((), jnp.float32))
 
     def __add__(self, other: "MoeStats") -> "MoeStats":
-        return MoeStats(self.counts + other.counts, self.drops + other.drops)
+        return MoeStats(self.counts + other.counts, self.drops + other.drops,
+                        self.rows + other.rows)
 
 
 def make_dispatch_plan(indices: jax.Array, *, num_experts: int,
@@ -182,41 +199,42 @@ def make_dispatch_plan(indices: jax.Array, *, num_experts: int,
     batched einsum). False: count-aligned ragged offsets sharing the pool
     (the Pallas gmm backend's group-aligned layout — absorbs imbalance).
     """
-    T, K = indices.shape
-    EL = local_experts or num_experts
-    flat = indices.reshape(-1).astype(jnp.int32) - expert_offset
-    local = (flat >= 0) & (flat < EL)
-    key = jnp.where(local, flat, EL).astype(jnp.int32)    # non-local -> sentinel
-    order = jnp.argsort(key, stable=True)                 # (T*K,)
-    sorted_key = key[order]
+    with jax.named_scope("dispatch"):
+        T, K = indices.shape
+        EL = local_experts or num_experts
+        flat = indices.reshape(-1).astype(jnp.int32) - expert_offset
+        local = (flat >= 0) & (flat < EL)
+        key = jnp.where(local, flat, EL).astype(jnp.int32)    # non-local -> sentinel
+        order = jnp.argsort(key, stable=True)                 # (T*K,)
+        sorted_key = key[order]
 
-    counts_all = jnp.bincount(key, length=EL + 1)         # Stage 2 histogram
-    counts = counts_all[:EL].astype(jnp.int32)
-    if uniform_capacity:
-        cap = pool_rows // EL
-        group_sizes = jnp.full((EL,), cap, jnp.int32)
-        offsets = (jnp.arange(EL + 1) * cap).astype(jnp.int32)
-    else:
-        gs_aligned = ((counts + align - 1) // align) * align
-        cum = jnp.minimum(jnp.cumsum(gs_aligned), pool_rows)
-        offsets = jnp.concatenate([jnp.zeros((1,), cum.dtype), cum])  # (EL+1,)
-        group_sizes = (offsets[1:] - offsets[:-1]).astype(jnp.int32)
+        counts_all = jnp.bincount(key, length=EL + 1)         # Stage 2 histogram
+        counts = counts_all[:EL].astype(jnp.int32)
+        if uniform_capacity:
+            cap = pool_rows // EL
+            group_sizes = jnp.full((EL,), cap, jnp.int32)
+            offsets = (jnp.arange(EL + 1) * cap).astype(jnp.int32)
+        else:
+            gs_aligned = ((counts + align - 1) // align) * align
+            cum = jnp.minimum(jnp.cumsum(gs_aligned), pool_rows)
+            offsets = jnp.concatenate([jnp.zeros((1,), cum.dtype), cum])  # (EL+1,)
+            group_sizes = (offsets[1:] - offsets[:-1]).astype(jnp.int32)
 
-    # position of each sorted element within its expert group
-    starts = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32),
-         jnp.cumsum(counts_all)[:-1].astype(jnp.int32)])             # (EL+1,)
-    pos_sorted = jnp.arange(T * K, dtype=jnp.int32) - starts[sorted_key]
+        # position of each sorted element within its expert group
+        starts = jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32),
+             jnp.cumsum(counts_all)[:-1].astype(jnp.int32)])             # (EL+1,)
+        pos_sorted = jnp.arange(T * K, dtype=jnp.int32) - starts[sorted_key]
 
-    safe_key = jnp.minimum(sorted_key, EL - 1)
-    slot_sorted = offsets[safe_key].astype(jnp.int32) + pos_sorted
-    valid_sorted = (sorted_key < EL) & (pos_sorted < group_sizes[safe_key])
-    slot_sorted = jnp.where(valid_sorted, slot_sorted, pool_rows)    # OOB
+        safe_key = jnp.minimum(sorted_key, EL - 1)
+        slot_sorted = offsets[safe_key].astype(jnp.int32) + pos_sorted
+        valid_sorted = (sorted_key < EL) & (pos_sorted < group_sizes[safe_key])
+        slot_sorted = jnp.where(valid_sorted, slot_sorted, pool_rows)    # OOB
 
-    slot = jnp.zeros((T * K,), jnp.int32).at[order].set(slot_sorted)
-    valid = jnp.zeros((T * K,), bool).at[order].set(valid_sorted)
-    drops = jnp.sum(local) - jnp.sum(valid_sorted)
-    return DispatchPlan(slot, valid, counts, group_sizes, int(pool_rows), drops)
+        slot = jnp.zeros((T * K,), jnp.int32).at[order].set(slot_sorted)
+        valid = jnp.zeros((T * K,), bool).at[order].set(valid_sorted)
+        drops = jnp.sum(local) - jnp.sum(valid_sorted)
+        return DispatchPlan(slot, valid, counts, group_sizes, int(pool_rows), drops)
 
 
 def pool_size(tokens: int, top_k: int, num_experts: int, local_experts: int,
@@ -251,48 +269,49 @@ def grouped_ffn(gate_w, up_w, down_w, pool_x, group_sizes, backend: str,
                       contraction (costs EL dense matmuls, same as XLA's
                       CPU lowering of lax.ragged_dot).
     """
-    cons = constrain or (lambda x, n: x)
-    if backend == "pallas":
-        from repro.kernels.ops import gmm, fused_swiglu
-        g = gmm(pool_x, gate_w.astype(pool_x.dtype), group_sizes)
-        u = gmm(pool_x, up_w.astype(pool_x.dtype), group_sizes)
-        h = fused_swiglu(g, u)
-        h = checkpoint_name(h, "moe_hidden")
-        return gmm(h, down_w.astype(pool_x.dtype), group_sizes)
-    if backend == "ragged":
-        # NOT lax.ragged_dot: XLA's SPMD partitioner rewrites ragged_dot's
-        # group_sizes operand into per-shard windows when the expert dim is
-        # sharded, and the rewritten values leak into every OTHER consumer
-        # of group_sizes (negative sizes -> phantom drops, diverged loss on
-        # any mesh with an ep/tp axis). A 0/1 expert mask partitions like
-        # any einsum and adds exact zeros, so the values are unchanged.
+    with jax.named_scope("ffn"):
+        cons = constrain or (lambda x, n: x)
+        if backend == "pallas":
+            from repro.kernels.ops import gmm, fused_swiglu
+            g = gmm(pool_x, gate_w.astype(pool_x.dtype), group_sizes)
+            u = gmm(pool_x, up_w.astype(pool_x.dtype), group_sizes)
+            h = fused_swiglu(g, u)
+            h = checkpoint_name(h, "moe_hidden")
+            return gmm(h, down_w.astype(pool_x.dtype), group_sizes)
+        if backend == "ragged":
+            # NOT lax.ragged_dot: XLA's SPMD partitioner rewrites ragged_dot's
+            # group_sizes operand into per-shard windows when the expert dim is
+            # sharded, and the rewritten values leak into every OTHER consumer
+            # of group_sizes (negative sizes -> phantom drops, diverged loss on
+            # any mesh with an ep/tp axis). A 0/1 expert mask partitions like
+            # any einsum and adds exact zeros, so the values are unchanged.
+            EL = gate_w.shape[0]
+            ends = jnp.cumsum(group_sizes)
+            e_row = jnp.searchsorted(ends, jnp.arange(pool_x.shape[0]),
+                                     side="right")          # slack rows -> EL
+            oh = jax.nn.one_hot(e_row, EL, dtype=pool_x.dtype)      # (M, EL)
+
+            def masked(h, w, sub):                          # h:(M,a) w:(EL,a,b)
+                return jnp.einsum(f"em{sub[-1]},me->m{sub[-1]}",
+                                  jnp.einsum(f"m{sub[0]},e{sub}->em{sub[-1]}",
+                                             h, w.astype(pool_x.dtype)), oh)
+
+            g = masked(pool_x, gate_w, "df")
+            u = masked(pool_x, up_w, "df")
+            h = jax.nn.silu(g) * u
+            h = checkpoint_name(h, "moe_hidden")
+            return masked(h, down_w, "fd")
+        # 'xla': uniform capacity — (EL, C, d) batched matmul
         EL = gate_w.shape[0]
-        ends = jnp.cumsum(group_sizes)
-        e_row = jnp.searchsorted(ends, jnp.arange(pool_x.shape[0]),
-                                 side="right")          # slack rows -> EL
-        oh = jax.nn.one_hot(e_row, EL, dtype=pool_x.dtype)      # (M, EL)
-
-        def masked(h, w, sub):                          # h:(M,a) w:(EL,a,b)
-            return jnp.einsum(f"em{sub[-1]},me->m{sub[-1]}",
-                              jnp.einsum(f"m{sub[0]},e{sub}->em{sub[-1]}",
-                                         h, w.astype(pool_x.dtype)), oh)
-
-        g = masked(pool_x, gate_w, "df")
-        u = masked(pool_x, up_w, "df")
-        h = jax.nn.silu(g) * u
+        M, d = pool_x.shape
+        C = M // EL
+        xb = cons(pool_x.reshape(EL, C, d), "moe_pool")
+        g = jnp.einsum("ecd,edf->ecf", xb, gate_w.astype(pool_x.dtype))
+        u = jnp.einsum("ecd,edf->ecf", xb, up_w.astype(pool_x.dtype))
+        h = cons(jax.nn.silu(g) * u, "moe_hidden")
         h = checkpoint_name(h, "moe_hidden")
-        return masked(h, down_w, "fd")
-    # 'xla': uniform capacity — (EL, C, d) batched matmul
-    EL = gate_w.shape[0]
-    M, d = pool_x.shape
-    C = M // EL
-    xb = cons(pool_x.reshape(EL, C, d), "moe_pool")
-    g = jnp.einsum("ecd,edf->ecf", xb, gate_w.astype(pool_x.dtype))
-    u = jnp.einsum("ecd,edf->ecf", xb, up_w.astype(pool_x.dtype))
-    h = cons(jax.nn.silu(g) * u, "moe_hidden")
-    h = checkpoint_name(h, "moe_hidden")
-    out = jnp.einsum("ecf,efd->ecd", h, down_w.astype(pool_x.dtype))
-    return out.reshape(M, d)
+        out = jnp.einsum("ecf,efd->ecd", h, down_w.astype(pool_x.dtype))
+        return out.reshape(M, d)
 
 
 # ----------------------------------------------------------------------------
@@ -345,26 +364,28 @@ def dispatch_compute_combine(gate_w, up_w, down_w, x, r: RouterOut, moe_cfg,
         pass
 
     # inverse map: pool row -> source token (paper: mlp_in = input[input_indices])
-    tok_flat = jnp.arange(T * K, dtype=jnp.int32) // K
-    inv_token = jnp.zeros((rows,), jnp.int32).at[plan.slot].set(
-        tok_flat, mode="drop")
-    pool_valid = jnp.zeros((rows,), bool).at[plan.slot].set(
-        plan.valid, mode="drop")
-    pool_x = x[inv_token] * pool_valid[:, None].astype(x.dtype)
-    pool_x = checkpoint_name(pool_x, "moe_dispatch")
+    with jax.named_scope("dispatch"):
+        tok_flat = jnp.arange(T * K, dtype=jnp.int32) // K
+        inv_token = jnp.zeros((rows,), jnp.int32).at[plan.slot].set(
+            tok_flat, mode="drop")
+        pool_valid = jnp.zeros((rows,), bool).at[plan.slot].set(
+            plan.valid, mode="drop")
+        pool_x = x[inv_token] * pool_valid[:, None].astype(x.dtype)
+        pool_x = checkpoint_name(pool_x, "moe_dispatch")
 
     pool_y = grouped_ffn(gate_w, up_w, down_w, pool_x, plan.group_sizes,
                          backend, constrain=constrain)
 
     # ---- Stage 5: weighted combine --------------------------------------
-    safe_slot = jnp.minimum(plan.slot, rows - 1)
-    yk = pool_y[safe_slot] * plan.valid[:, None].astype(pool_y.dtype)
-    yk = yk.reshape(T, K, d)
-    if backend == "pallas":
-        from repro.kernels.ops import combine as combine_kernel
-        out = combine_kernel(yk, r.weights.astype(pool_y.dtype))
-    else:
-        out = jnp.einsum("tkd,tk->td", yk, r.weights.astype(yk.dtype))
+    with jax.named_scope("combine"):
+        safe_slot = jnp.minimum(plan.slot, rows - 1)
+        yk = pool_y[safe_slot] * plan.valid[:, None].astype(pool_y.dtype)
+        yk = yk.reshape(T, K, d)
+        if backend == "pallas":
+            from repro.kernels.ops import combine as combine_kernel
+            out = combine_kernel(yk, r.weights.astype(pool_y.dtype))
+        else:
+            out = jnp.einsum("tkd,tk->td", yk, r.weights.astype(yk.dtype))
     return out, plan
 
 
@@ -389,7 +410,7 @@ def _moe_dense(p, x, moe_cfg, *, backend: str, constrain=None,
         out = out + _shared_expert(p, x)
     counts = plan.counts if placement is None else plan.counts[placement]
     stats = MoeStats(counts.astype(jnp.float32),
-                     plan.drops.astype(jnp.float32))
+                     plan.drops.astype(jnp.float32), PoolRows(plan.pool_rows))
     return out, r, stats
 
 
@@ -496,9 +517,11 @@ def moe_fsmoe_ep(p, x, moe_cfg, *, mesh, ep_axis: str = "model",
                 raise NotImplementedError(
                     "stage1='a2a' does not compose with expert-TP yet; use "
                     "the allgather Stage 1 for ep x tp plans")
-            return _fsmoe_a2a_body(gate, up, down, router_w, xl, moe_cfg,
-                                   ep_axis=ep_axis, ep=ep, manual=manual,
-                                   batch_axes=batch_axes, placement=pl)
+            out_local, aux, z, stats = _fsmoe_a2a_body(
+                gate, up, down, router_w, xl, moe_cfg, ep_axis=ep_axis,
+                ep=ep, manual=manual, batch_axes=batch_axes, placement=pl)
+            pool.append(stats.rows.n)
+            return out_local, aux, z, stats._replace(rows=PoolRows())
         # Router on local tokens (router replicated — paper §3.1).
         r = route(xl, router_w, num_experts=E,
                   top_k=moe_cfg.experts_per_token,
@@ -507,9 +530,10 @@ def moe_fsmoe_ep(p, x, moe_cfg, *, mesh, ep_axis: str = "model",
         # already computed on global ids inside route)
         idx = r.indices if pl is None else pl[r.indices]
         # ---- Stage 1: allgather tokens + routing over the EP axis -------
-        x_g = jax.lax.all_gather(xl, ep_axis, tiled=True)
-        w_g = jax.lax.all_gather(r.weights, ep_axis, tiled=True)
-        i_g = jax.lax.all_gather(idx, ep_axis, tiled=True)
+        with jax.named_scope("exchange"):
+            x_g = jax.lax.all_gather(xl, ep_axis, tiled=True)
+            w_g = jax.lax.all_gather(r.weights, ep_axis, tiled=True)
+            i_g = jax.lax.all_gather(idx, ep_axis, tiled=True)
         r_g = RouterOut(w_g, i_g, r.aux_loss, r.z_loss)
         # ---- Stages 2-5 on the local expert (and d_ff) slice -------------
         rank = jax.lax.axis_index(ep_axis)
@@ -517,20 +541,23 @@ def moe_fsmoe_ep(p, x, moe_cfg, *, mesh, ep_axis: str = "model",
             gate, up, down, x_g, r_g, moe_cfg,
             expert_offset=rank * EL, local_experts=EL,
             backend=stage45_backend(moe_cfg), dropless=dropless)
-        if tp_axis is not None:
-            # expert-TP: sum the per-d_ff-shard partial outputs (the combine
-            # is linear in the expert rows, so summing after it is exact)
-            out_partial = jax.lax.psum(out_partial, tp_axis)
-        # ---- Stage 5 tail: reduce-scatter to local tokens ----------------
-        out_local = jax.lax.psum_scatter(out_partial, ep_axis,
-                                         scatter_dimension=0, tiled=True)
-        aux = r.aux_loss
-        z = r.z_loss
-        for ax in manual:
-            aux = jax.lax.pmean(aux, ax)
-            z = jax.lax.pmean(z, ax)
-        stats = _fsmoe_stats(plan.counts, plan.drops, ep_axis=ep_axis, ep=ep,
-                             batch_axes=batch_axes, manual=manual)
+        pool.append(plan.pool_rows)
+        with jax.named_scope("exchange"):
+            if tp_axis is not None:
+                # expert-TP: sum the per-d_ff-shard partial outputs (the
+                # combine is linear in the expert rows, so summing after it
+                # is exact)
+                out_partial = jax.lax.psum(out_partial, tp_axis)
+            # ---- Stage 5 tail: reduce-scatter to local tokens ------------
+            out_local = jax.lax.psum_scatter(out_partial, ep_axis,
+                                             scatter_dimension=0, tiled=True)
+            aux = r.aux_loss
+            z = r.z_loss
+            for ax in manual:
+                aux = jax.lax.pmean(aux, ax)
+                z = jax.lax.pmean(z, ax)
+            stats = _fsmoe_stats(plan.counts, plan.drops, ep_axis=ep_axis,
+                                 ep=ep, batch_axes=batch_axes, manual=manual)
         if pl is not None:     # report counts back in global expert order
             stats = MoeStats(stats.counts[pl], stats.drops)
         return out_local, aux, z, stats
@@ -547,11 +574,16 @@ def moe_fsmoe_ep(p, x, moe_cfg, *, mesh, ep_axis: str = "model",
     # against the naive MoE (tests/test_distributed.py) are the check.
     from repro.kernels import ops
     check = stage45_backend(moe_cfg) != "pallas" or ops.vma_checkable()
+    # one rank's slot-pool rows: static, so they leave the body through its
+    # trace and not as an output
+    pool = []
     out, aux, z, stats = jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(token_spec, P(), P(), MoeStats(P(None), P())),
         axis_names=manual, check_vma=check)(*operands)
+    stats = stats._replace(rows=PoolRows(
+        pool[-1] * ep * math.prod(mesh.shape[a] for a in batch_axes)))
     out = checkpoint_name(out, "moe_out")
     if moe_cfg.num_shared_experts:
         out = out + _shared_expert(p, x)
@@ -591,24 +623,28 @@ def _fsmoe_a2a_body(gate, up, down, router_w, xl, moe_cfg, *, ep_axis, ep,
     Cd = round_up(int(math.ceil(moe_cfg.capacity_factor * T_loc * K / ep)), 8)
     plan = make_dispatch_plan(dest, num_experts=ep, pool_rows=ep * Cd,
                               uniform_capacity=True)
-    tok_flat = jnp.arange(T_loc * K, dtype=jnp.int32) // K
-    inv_tok = jnp.zeros((ep * Cd,), jnp.int32).at[plan.slot].set(
-        tok_flat, mode="drop")
-    pool_valid = jnp.zeros((ep * Cd,), bool).at[plan.slot].set(
-        plan.valid, mode="drop")
-    send_x = xl[inv_tok] * pool_valid[:, None].astype(xl.dtype)
-    flat_idx = idx.reshape(-1)
-    flat_w = r.weights.reshape(-1)
-    send_e = jnp.full((ep * Cd,), -1, jnp.int32).at[plan.slot].set(
-        flat_idx, mode="drop")
-    send_w = jnp.zeros((ep * Cd,), jnp.float32).at[plan.slot].set(
-        flat_w, mode="drop")
-    send_e = jnp.where(pool_valid, send_e, -1)
+    with jax.named_scope("dispatch"):
+        tok_flat = jnp.arange(T_loc * K, dtype=jnp.int32) // K
+        inv_tok = jnp.zeros((ep * Cd,), jnp.int32).at[plan.slot].set(
+            tok_flat, mode="drop")
+        pool_valid = jnp.zeros((ep * Cd,), bool).at[plan.slot].set(
+            plan.valid, mode="drop")
+        send_x = xl[inv_tok] * pool_valid[:, None].astype(xl.dtype)
+        flat_idx = idx.reshape(-1)
+        flat_w = r.weights.reshape(-1)
+        send_e = jnp.full((ep * Cd,), -1, jnp.int32).at[plan.slot].set(
+            flat_idx, mode="drop")
+        send_w = jnp.zeros((ep * Cd,), jnp.float32).at[plan.slot].set(
+            flat_w, mode="drop")
+        send_e = jnp.where(pool_valid, send_e, -1)
 
     # --- all-to-all ------------------------------------------------------
-    a2a = lambda a: jax.lax.all_to_all(
-        a.reshape((ep, Cd) + a.shape[1:]), ep_axis, 0, 0, tiled=False
-    ).reshape((ep * Cd,) + a.shape[1:])
+    def a2a(a):
+        with jax.named_scope("exchange"):
+            return jax.lax.all_to_all(
+                a.reshape((ep, Cd) + a.shape[1:]), ep_axis, 0, 0, tiled=False
+            ).reshape((ep * Cd,) + a.shape[1:])
+
     recv_x = a2a(send_x)
     recv_e = a2a(send_e)
     recv_w = a2a(send_w)
@@ -631,23 +667,26 @@ def _fsmoe_a2a_body(gate, up, down, router_w, xl, moe_cfg, *, ep_axis, ep,
 
     # --- reverse all-to-all + per-token sum over K slots ------------------
     back = a2a(out_rows)
-    safe_slot = jnp.minimum(plan.slot, ep * Cd - 1)
-    yk = back[safe_slot] * plan.valid[:, None].astype(back.dtype)
-    out_local = yk.reshape(T_loc, K, d).sum(axis=1)
+    with jax.named_scope("combine"):
+        safe_slot = jnp.minimum(plan.slot, ep * Cd - 1)
+        yk = back[safe_slot] * plan.valid[:, None].astype(back.dtype)
+        out_local = yk.reshape(T_loc, K, d).sum(axis=1)
 
-    aux, z = r.aux_loss, r.z_loss
-    for ax in manual:
-        aux = jax.lax.pmean(aux, ax)
-        z = jax.lax.pmean(z, ax)
-    # send-side capacity drops (outer plan) + receive-side pool overflow
-    # (inner plan); counts come from the received rows each rank dispatched
-    # among its local experts
-    stats = _fsmoe_stats(inner_plan.counts, plan.drops, ep_axis=ep_axis,
-                         ep=ep, batch_axes=batch_axes, manual=manual,
-                         extra_drops=inner_plan.drops)
+    with jax.named_scope("exchange"):
+        aux, z = r.aux_loss, r.z_loss
+        for ax in manual:
+            aux = jax.lax.pmean(aux, ax)
+            z = jax.lax.pmean(z, ax)
+        # send-side capacity drops (outer plan) + receive-side pool overflow
+        # (inner plan); counts come from the received rows each rank
+        # dispatched among its local experts
+        stats = _fsmoe_stats(inner_plan.counts, plan.drops, ep_axis=ep_axis,
+                             ep=ep, batch_axes=batch_axes, manual=manual,
+                             extra_drops=inner_plan.drops)
     if placement is not None:  # back to global expert order
         stats = MoeStats(stats.counts[placement], stats.drops)
-    return out_local, aux, z, stats
+    return out_local, aux, z, stats._replace(
+        rows=PoolRows(inner_plan.pool_rows))
 
 
 # ----------------------------------------------------------------------------
@@ -684,7 +723,9 @@ def moe_etp_shard_map(p, x, moe_cfg, *, mesh, tp_axis: str = "model",
         out_partial, plan = dispatch_compute_combine(
             gate, up, down, xl, rd, moe_cfg, backend="xla",
             dropless=dropless)
-        out = jax.lax.psum(out_partial, tp_axis)
+        pool.append(plan.pool_rows)
+        with jax.named_scope("exchange"):
+            out = jax.lax.psum(out_partial, tp_axis)
         aux, z = r.aux_loss, r.z_loss
         for ax in manual:
             aux = jax.lax.pmean(aux, ax)
@@ -710,11 +751,14 @@ def moe_etp_shard_map(p, x, moe_cfg, *, mesh, tp_axis: str = "model",
     if placement is not None:
         operands.append(jnp.asarray(placement, jnp.int32))
         in_specs.append(P(None))
+    pool = []      # one token shard's slot-pool rows, as under EP
     out, aux, z, stats = jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(token_spec, P(), P(), MoeStats(P(None), P())),
         axis_names=manual)(*operands)
+    stats = stats._replace(rows=PoolRows(
+        pool[-1] * math.prod(mesh.shape[a] for a in batch_axes)))
     out = checkpoint_name(out, "moe_out")
     if moe_cfg.num_shared_experts:
         out = out + _shared_expert(p, x)
@@ -741,7 +785,8 @@ def sparse_moe_block(p, x, cfg, *, mesh=None, ep_axis: str = "model",
         out, r = moe_naive(p, xt, m, placement=placement)
         # stats from the router's global ids — already placement-free
         one_hot = jax.nn.one_hot(r.indices, m.num_experts, dtype=jnp.float32)
-        stats = MoeStats(one_hot.sum((0, 1)), jnp.zeros((), jnp.float32))
+        stats = MoeStats(one_hot.sum((0, 1)), jnp.zeros((), jnp.float32),
+                         PoolRows(B * S * m.num_experts))  # every expert
         return out.reshape(B, S, d), r.aux_loss, r.z_loss, stats
     use_ep = (m.moe_impl == "fsmoe" and mesh is not None
               and ep_axis in mesh.shape

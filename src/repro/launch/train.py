@@ -31,6 +31,12 @@ is kept; byte-tokenizer ids are valid ids in it):
   python -m repro.launch.train --arch mula-7b-a1b --scale full --layers 1 \
       --batch 2 --seq 2048 --steps 5
 
+Usage (profile steps 3 and 4 into runs/mula7b/profile, for TensorBoard or
+``jax.profiler.ProfileData``; device ops carry the step's named scopes in
+their HLO metadata, host spans are train.input, train.fetch and ckpt.save):
+  PYTHONPATH=src python -m repro.launch.train --arch mula-7b-a1b --scale smoke \
+      --steps 10 --out runs/mula7b --profile 3:5
+
 The legacy ``--mesh dp[,pp][,model]`` spec still works: it is translated to
 a ParallelPlan via ``ParallelPlan.from_legacy`` (the old role inference on
 the 'model' axis — EP when the expert count divides it, TP otherwise).
@@ -49,6 +55,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs import (ParallelConfig, TrainConfig, get_config, reduced)
 from repro.data import ByteTokenizer, ShardedDataLoader, preprocess_corpus
@@ -102,6 +109,19 @@ def _env_int(name: str):
     return int(v) if v else None
 
 
+def _profile_steps(spec: str | None):
+    """'START:STOP' -> (START, STOP), the steps [START, STOP) to profile."""
+    if spec is None:
+        return None
+    try:
+        start, stop = (int(x) for x in spec.split(":"))
+    except ValueError:
+        raise ValueError(f"--profile wants START:STOP, not {spec!r}")
+    if not 0 <= start < stop:
+        raise ValueError(f"--profile {spec}: need 0 <= START < STOP")
+    return start, stop
+
+
 def run(arch: str, *, scale: str = "smoke", steps: int = 100, batch: int = 8,
         seq: int = 128, out: str = "runs/default", lr: float = 1e-3,
         moe_impl: str = None, fur: bool = False, ckpt_interval: int = 50,
@@ -116,7 +136,7 @@ def run(arch: str, *, scale: str = "smoke", steps: int = 100, batch: int = 8,
         rebalance: str = None, rebalance_force_at: int = None,
         n_buffer: int = 2,
         inject_hard_at: int = None, inject_soft_at: int = None,
-        max_relaunches: int = 8) -> RunResult:
+        max_relaunches: int = 8, profile: str = None) -> RunResult:
     # opt_shard/pp_schedule: None = not passed (the --parallel spec's opt=/
     # schedule= options apply); an explicit value — including the defaults
     # 'none'/'1f1b' — overrides the spec.
@@ -127,6 +147,7 @@ def run(arch: str, *, scale: str = "smoke", steps: int = 100, batch: int = 8,
     if mesh and parallel:
         raise ValueError("--mesh and --parallel are mutually exclusive "
                          "(--mesh is the legacy spelling of --parallel)")
+    prof_steps = _profile_steps(profile)
     os.makedirs(out, exist_ok=True)
 
     # cfg is pure python — build it before the plan resolves (on the CPU
@@ -352,24 +373,45 @@ def run(arch: str, *, scale: str = "smoke", steps: int = 100, batch: int = 8,
     history = {}          # keyed by step: replays after restore overwrite
     t0 = time.time()
 
+    profiling = [False]
+
+    def profiler(step):
+        """Start the trace at the first profiled step, stop it at the
+        step after the last (so that step's checkpoint save is in it)."""
+        if prof_steps is None:
+            return
+        if step == prof_steps[0] and not profiling[0]:
+            jax.profiler.start_trace(os.path.join(out, "profile"))
+            profiling[0] = True
+        elif step >= prof_steps[1] and profiling[0]:
+            jax.profiler.stop_trace()
+            profiling[0] = False
+
     def train_one_step(state, step):
         if step == inject_hard_at and not injected["hard"]:
             injected["hard"] = True
             print(f"  !! injected HARD failure on node 0 @ step {step}")
             raise NodeFailure(cluster.active[0].node_id, "hard")
-        batch_np = next(batches)     # == loader.batch(step): pure in step
-        if cfg.arch_type == "vlm":
-            batch_np["image_embeds"] = np.zeros(
-                (batch, cfg.num_prefix_embeds, cfg.d_model), np.float32)
-        if cfg.arch_type == "audio":
-            half = seq // 2
-            batch_np = {"frame_embeds": np.random.default_rng(step).normal(
-                            size=(batch, half, cfg.d_model)).astype(np.float32),
-                        "tokens": batch_np["tokens"][:, :half],
-                        "labels": batch_np["labels"][:, :half]}
-        batch_dev = jax.tree.map(
-            lambda a: jax.device_put(a, bsh) if bsh is not None
-            else jnp.asarray(a), batch_np)
+        profiler(step)
+        with StepTraceAnnotation("train", step_num=step):
+            return step_body(state, step)
+
+    def step_body(state, step):
+        with TraceAnnotation("train.input"):
+            batch_np = next(batches)     # == loader.batch(step): pure in step
+            if cfg.arch_type == "vlm":
+                batch_np["image_embeds"] = np.zeros(
+                    (batch, cfg.num_prefix_embeds, cfg.d_model), np.float32)
+            if cfg.arch_type == "audio":
+                half = seq // 2
+                batch_np = {
+                    "frame_embeds": np.random.default_rng(step).normal(
+                        size=(batch, half, cfg.d_model)).astype(np.float32),
+                    "tokens": batch_np["tokens"][:, :half],
+                    "labels": batch_np["labels"][:, :half]}
+            batch_dev = jax.tree.map(
+                lambda a: jax.device_put(a, bsh) if bsh is not None
+                else jnp.asarray(a), batch_np)
         state, metrics = live["step_fn"](state, batch_dev)
         # one host sync per step: batch every fetched metric into a single
         # device_get — per-metric float()/np.asarray() calls would each
@@ -382,10 +424,9 @@ def run(arch: str, *, scale: str = "smoke", steps: int = 100, batch: int = 8,
                  "grad_norm": metrics["grad_norm"]}
         if "moe_drops" in metrics:
             fetch["moe_drops"] = metrics["moe_drops"]
-            fetch["moe_load"] = metrics["moe_load"]
-            if controller is not None:
-                fetch["moe_counts"] = metrics["moe_counts"]
-        vals = jax.device_get(fetch)
+            fetch["moe_counts"] = metrics["moe_counts"]
+        with TraceAnnotation("train.fetch"):
+            vals = jax.device_get(fetch)
         loss = float(vals["loss"])
         gnorm = float(vals["grad_norm"])
         per_rank = [loss]
@@ -398,13 +439,13 @@ def run(arch: str, *, scale: str = "smoke", steps: int = 100, batch: int = 8,
         moe_line = ""
         if "moe_drops" in vals:        # per-expert routing telemetry
             drops = float(vals["moe_drops"])
-            load = np.asarray(vals["moe_load"])
+            counts = np.asarray(vals["moe_counts"])
             history[step]["moe_drops"] = drops
-            history[step]["moe_load_max"] = float(load.max()) if load.size \
-                else 0.0
+            history[step]["moe_load_max"] = float(
+                counts.max() / max(counts.sum(), 1.0)) if counts.size else 0.0
             moe_line = (f" drops {drops:.0f} "
                         f"load_max {history[step]['moe_load_max']:.3f}")
-        if controller is not None and "moe_counts" in vals:
+        if controller is not None:
             # telemetry-driven EP rebalancing: feed the windowed counts to
             # the controller; at a window boundary (or the forced step) move
             # the expert stacks + EPSO states and rebuild the step. The
@@ -447,10 +488,14 @@ def run(arch: str, *, scale: str = "smoke", steps: int = 100, batch: int = 8,
             controller.reset_window()
         return state
 
-    state, end_step, relaunches = run_with_failure_handling(
-        train_one_step, state=state, checkpointer=ckpt, cluster=cluster,
-        num_steps=steps, monitor=NaNMonitor(), start_step=start,
-        max_relaunches=max_relaunches, on_relaunch=on_relaunch)
+    try:
+        state, end_step, relaunches = run_with_failure_handling(
+            train_one_step, state=state, checkpointer=ckpt, cluster=cluster,
+            num_steps=steps, monitor=NaNMonitor(), start_step=start,
+            max_relaunches=max_relaunches, on_relaunch=on_relaunch)
+    finally:
+        if profiling[0]:           # the run ended inside the profiled steps
+            jax.profiler.stop_trace()
 
     result = RunResult(history[s] for s in sorted(history))
     result.relaunches = relaunches
@@ -582,6 +627,9 @@ def main():
     ap.add_argument("--inject-soft-at", type=int, default=None,
                     help="inject one soft (NaN) failure at this step "
                          "(also REPRO_INJECT_SOFT_AT)")
+    ap.add_argument("--profile", default=None, metavar="START:STOP",
+                    help="take a jax.profiler trace of steps [START, STOP) "
+                         "into <out>/profile (off by default)")
     args = ap.parse_args()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -599,7 +647,7 @@ def main():
         rebalance_force_at=args.rebalance_force_at,
         log_every=args.log_every, n_buffer=args.n_buffer,
         inject_hard_at=args.inject_hard_at,
-        inject_soft_at=args.inject_soft_at)
+        inject_soft_at=args.inject_soft_at, profile=args.profile)
 
 
 if __name__ == "__main__":

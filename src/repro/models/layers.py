@@ -141,38 +141,43 @@ def _blockwise_attention(q, k, v, *, causal: bool, window: int,
     # check-failed the SPMD partitioner inside partial-auto shard_map
     # regions (the per-stage pipeline executor) — "Check failed:
     # sharding.IsManualSubgroup()". A carried counter is bit-identical.
+    # The scan bodies open the caller's scope again: JAX re-emits parts of
+    # a scan under remat (the recompute, the hoisted masks) with the name
+    # stack of where it does so, which drops the scopes around the scan.
     def q_step(qi, _):
-        qt = qr[:, :, :, qi].astype(jnp.float32) * scale   # (B,nkv,g,qb,hd)
-        qp = q_pos[qi]                                     # (qb,)
+        with jax.named_scope("attn"):
+            qt = qr[:, :, :, qi].astype(jnp.float32) * scale   # (B,nkv,g,qb,hd)
+            qp = q_pos[qi]                                     # (qb,)
 
-        def kv_step(carry, _):
-            m, l, acc, ki = carry
-            kt = kr[:, :, ki].astype(jnp.float32)          # (B,nkv,kb,hd)
-            vt = vr[:, :, ki].astype(jnp.float32)
-            s = jnp.einsum("bngqh,bnkh->bngqk", qt, kt)    # (B,nkv,g,qb,kb)
-            kp = kv_pos[ki]
-            mask = jnp.ones((qb, kb), bool)
-            if causal:
-                mask &= qp[:, None] >= kp[None, :]
-            if window > 0:
-                mask &= qp[:, None] - kp[None, :] < window
-            mask &= (kp < Skv)[None, :]                    # kv padding
-            s = jnp.where(mask[None, None, None], s, neg)
-            m_new = jnp.maximum(m, s.max(-1))
-            p = jnp.exp(s - m_new[..., None])
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + p.sum(-1)
-            acc_new = acc * corr[..., None] + jnp.einsum(
-                "bngqk,bnkh->bngqh", p, vt)
-            return (m_new, l_new, acc_new, ki + 1), None
+            def kv_step(carry, _):
+                with jax.named_scope("attn"):
+                    m, l, acc, ki = carry
+                    kt = kr[:, :, ki].astype(jnp.float32)      # (B,nkv,kb,hd)
+                    vt = vr[:, :, ki].astype(jnp.float32)
+                    s = jnp.einsum("bngqh,bnkh->bngqk", qt, kt)  # (B,nkv,g,qb,kb)
+                    kp = kv_pos[ki]
+                    mask = jnp.ones((qb, kb), bool)
+                    if causal:
+                        mask &= qp[:, None] >= kp[None, :]
+                    if window > 0:
+                        mask &= qp[:, None] - kp[None, :] < window
+                    mask &= (kp < Skv)[None, :]                # kv padding
+                    s = jnp.where(mask[None, None, None], s, neg)
+                    m_new = jnp.maximum(m, s.max(-1))
+                    p = jnp.exp(s - m_new[..., None])
+                    corr = jnp.exp(m - m_new)
+                    l_new = l * corr + p.sum(-1)
+                    acc_new = acc * corr[..., None] + jnp.einsum(
+                        "bngqk,bnkh->bngqh", p, vt)
+                    return (m_new, l_new, acc_new, ki + 1), None
 
-        m0 = jnp.full((B, nkv, groups, qb), neg)
-        l0 = jnp.zeros((B, nkv, groups, qb))
-        a0 = jnp.zeros((B, nkv, groups, qb, hd))
-        (m, l, acc, _), _ = jax.lax.scan(kv_step, (m0, l0, a0, jnp.int32(0)),
-                                         None, length=nk)
-        out = acc / jnp.maximum(l[..., None], 1e-30)
-        return qi + 1, out
+            m0 = jnp.full((B, nkv, groups, qb), neg)
+            l0 = jnp.zeros((B, nkv, groups, qb))
+            a0 = jnp.zeros((B, nkv, groups, qb, hd))
+            (m, l, acc, _), _ = jax.lax.scan(
+                kv_step, (m0, l0, a0, jnp.int32(0)), None, length=nk)
+            out = acc / jnp.maximum(l[..., None], 1e-30)
+            return qi + 1, out
 
     _, o = jax.lax.scan(q_step, jnp.int32(0), None,
                         length=nq)                         # (nq,B,nkv,g,qb,hd)
@@ -307,7 +312,8 @@ def init_embedding(rng, vocab: int, d: int) -> dict:
 
 
 def embed(params, tokens, dtype):
-    return params["table"].astype(dtype)[tokens]
+    with jax.named_scope("embed"):
+        return params["table"].astype(dtype)[tokens]
 
 
 def unembed(params, x):

@@ -20,6 +20,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import moe as moe_lib
@@ -153,8 +154,10 @@ def _dense_block(lp, h, cfg, rules, sac: str, causal=True):
                                          causal=causal), "attn", sac)
     mlp = _sac(lambda q, x: L.apply_mlp(q, x, cfg.mlp_activation, cons),
                "mlp", sac)
-    h = h + attn(lp["attn"], L.apply_norm(lp["ln1"], h, cfg.norm))
-    h = h + mlp(lp["mlp"], L.apply_norm(lp["ln2"], h, cfg.norm))
+    with jax.named_scope("attn"):
+        h = h + attn(lp["attn"], L.apply_norm(lp["ln1"], h, cfg.norm))
+    with jax.named_scope("mlp"):
+        h = h + mlp(lp["mlp"], L.apply_norm(lp["ln2"], h, cfg.norm))
     return cons(h, "act_btd")
 
 
@@ -180,9 +183,12 @@ def _moe_block(lp, h, cfg, rules, sac: str, mesh, placement=None):
         batch_axes=batch_axes, constrain=cons,
         c_align=c_align, tp_mesh=tp_mesh, tp_axis=tp_axis,
         placement=placement), "moe", sac)
-    h = h + attn(lp["attn"], L.apply_norm(lp["ln1"], h, cfg.norm))
-    mo, aux, z, stats = moe(lp["moe"], L.apply_norm(lp["ln2"], h, cfg.norm))
-    h = h + mo
+    with jax.named_scope("attn"):
+        h = h + attn(lp["attn"], L.apply_norm(lp["ln1"], h, cfg.norm))
+    with jax.named_scope("moe"):
+        mo, aux, z, stats = moe(lp["moe"],
+                                L.apply_norm(lp["ln2"], h, cfg.norm))
+        h = h + mo
     return cons(h, "act_btd"), aux, z, stats
 
 
@@ -202,9 +208,11 @@ def _xattn_block(lp, h, mem, cfg, rules, sac: str):
                                             memory=m), "attn", sac)
     mlp = _sac(lambda q, x: L.apply_mlp(q, x, cfg.mlp_activation, cons),
                "mlp", sac)
-    h = h + attn(lp["attn"], L.apply_norm(lp["ln1"], h, cfg.norm))
-    h = h + xatt(lp["xattn"], L.apply_norm(lp["lnx"], h, cfg.norm), mem)
-    h = h + mlp(lp["mlp"], L.apply_norm(lp["ln2"], h, cfg.norm))
+    with jax.named_scope("attn"):
+        h = h + attn(lp["attn"], L.apply_norm(lp["ln1"], h, cfg.norm))
+        h = h + xatt(lp["xattn"], L.apply_norm(lp["lnx"], h, cfg.norm), mem)
+    with jax.named_scope("mlp"):
+        h = h + mlp(lp["mlp"], L.apply_norm(lp["ln2"], h, cfg.norm))
     return cons(h, "act_btd")
 
 
@@ -227,18 +235,22 @@ def _scan_layers_aux(stacked, h, body, sac: str, num_experts: int,
     alongside the stacked params, so each layer dispatches against its own
     row (None — an empty pytree — scans through untouched)."""
     fn = block_remat(body, sac)
+    rows = []      # a layer's static PoolRows, kept out of the carry
 
     def step(carry, xs):
         lp, pl = xs
         h, aux, z, st = carry
         h, a, zz, s = fn(lp, h, pl)
-        return (h, aux + a, z + zz, st + s), None
+        rows.append(s.rows)
+        return (h, aux + a, z + zz, st + s._replace(rows=st.rows)), None
 
     (h, aux, z, st), _ = jax.lax.scan(
         step, (h, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32),
                moe_lib.MoeStats.zero(num_experts)),
         (stacked, placement))
-    return h, aux, z, st
+    n_layers = jax.tree.leaves(stacked)[0].shape[0]
+    return h, aux, z, st._replace(
+        rows=moe_lib.PoolRows(rows[-1].n * n_layers))
 
 
 # ----------------------------------------------------------------------------
@@ -312,11 +324,15 @@ def forward(params, batch: dict, cfg: ModelConfig, *,
         else:
             raise ValueError(at)
 
-    h = L.apply_norm(params["final_norm"], h, cfg.norm)
-    head = params.get("head", params["embed"])
-    logits = L.unembed(head, h)
-    logits = cons(logits, "logits")
-    return logits, aux
+    return _logits(params, h, cfg, cons), aux
+
+
+def _logits(params, h, cfg: ModelConfig, cons=L.no_constrain):
+    """The head up to the logits: final norm and unembedding."""
+    with jax.named_scope("head"):
+        h = L.apply_norm(params["final_norm"], h, cfg.norm)
+        return cons(L.unembed(params.get("head", params["embed"]), h),
+                    "logits")
 
 
 # ----------------------------------------------------------------------------
@@ -325,18 +341,19 @@ def forward(params, batch: dict, cfg: ModelConfig, *,
 
 def masked_ce(logits, labels, cfg: ModelConfig):
     """Masked next-token CE over padded-vocab logits. Returns (ce, ntok)."""
-    vp = padded_vocab(cfg)
-    logits = logits.astype(jnp.float32)
-    if vp != cfg.vocab_size:     # mask padded vocab columns out of the lse
-        pad_mask = jnp.arange(vp) >= cfg.vocab_size
-        logits = jnp.where(pad_mask, -1e9, logits)
-    mask = labels >= 0
-    safe = jnp.maximum(labels, 0)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    ll = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
-    nll = jnp.where(mask, lse - ll, 0.0)
-    ntok = jnp.maximum(mask.sum(), 1)
-    return nll.sum() / ntok, ntok
+    with jax.named_scope("head"):
+        vp = padded_vocab(cfg)
+        logits = logits.astype(jnp.float32)
+        if vp != cfg.vocab_size:     # mask padded vocab columns out of the lse
+            pad_mask = jnp.arange(vp) >= cfg.vocab_size
+            logits = jnp.where(pad_mask, -1e9, logits)
+        mask = labels >= 0
+        safe = jnp.maximum(labels, 0)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        ll = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
+        nll = jnp.where(mask, lse - ll, 0.0)
+        ntok = jnp.maximum(mask.sum(), 1)
+        return nll.sum() / ntok, ntok
 
 
 def loss_fn(params, batch, cfg: ModelConfig, *, rules=None, mesh=None,
@@ -357,10 +374,12 @@ def loss_fn(params, batch, cfg: ModelConfig, *, rules=None, mesh=None,
                "moe_z": aux["moe_z"] / max(cfg.num_layers, 1), "ntok": ntok}
     if "moe_stats" in aux:
         st = aux["moe_stats"]
-        counts = st.counts / max(cfg.num_layers, 1)   # per-layer mean -> T*K
-        metrics["moe_counts"] = counts
-        metrics["moe_load"] = counts / jnp.maximum(counts.sum(), 1.0)
+        nl = max(cfg.num_layers, 1)
+        metrics["moe_counts"] = st.counts / nl        # per-layer mean -> T*K
         metrics["moe_drops"] = st.drops               # summed over layers
+        # static, so a constant output: the pool rows the grouped matmul
+        # covers in a layer, in the units of moe_counts
+        metrics["moe_rows_computed"] = np.float32(st.rows.n / nl)
     return total, metrics
 
 
@@ -413,9 +432,7 @@ def pipeline_stage_forward(stage_lp, h, cfg: ModelConfig, *, sac: str = ""):
 def lm_head_ce(params, h, labels, cfg: ModelConfig):
     """Last-stage tail: final norm + unembed + masked CE — the same ops
     ``forward`` + ``loss_fn`` apply after the layer stack. Returns ce."""
-    h = L.apply_norm(params["final_norm"], h, cfg.norm)
-    head = params.get("head", params["embed"])
-    ce, _ = masked_ce(L.unembed(head, h), labels, cfg)
+    ce, _ = masked_ce(_logits(params, h, cfg), labels, cfg)
     return ce
 
 
@@ -469,23 +486,26 @@ def decode_step(params, tokens, cache: dict, index, cfg: ModelConfig, *,
     new_cache = dict(cache)
 
     def attn_step(lp, hh, kv):
-        a, kv2 = L.decode_attention(lp["attn"], L.apply_norm(lp["ln1"], hh,
-                                                             cfg.norm),
-                                    kv, index, cfg, constrain=cons)
-        return hh + a, kv2
+        with jax.named_scope("attn"):
+            a, kv2 = L.decode_attention(
+                lp["attn"], L.apply_norm(lp["ln1"], hh, cfg.norm), kv, index,
+                cfg, constrain=cons)
+            return hh + a, kv2
 
     if at in ("dense", "vlm", "moe"):
         def step(carry, xs):
             hh = carry
             lp, kv = xs
             hh, kv2 = attn_step(lp, hh, kv)
-            x2 = L.apply_norm(lp["ln2"], hh, cfg.norm)
-            if at == "moe":
-                mo, _, _, _ = moe_lib.sparse_moe_block(lp["moe"], x2, cfg,
-                                                       mesh=None)
-                hh = hh + mo
-            else:
-                hh = hh + L.apply_mlp(lp["mlp"], x2, cfg.mlp_activation, cons)
+            with jax.named_scope("moe" if at == "moe" else "mlp"):
+                x2 = L.apply_norm(lp["ln2"], hh, cfg.norm)
+                if at == "moe":
+                    mo, _, _, _ = moe_lib.sparse_moe_block(lp["moe"], x2, cfg,
+                                                           mesh=None)
+                    hh = hh + mo
+                else:
+                    hh = hh + L.apply_mlp(lp["mlp"], x2, cfg.mlp_activation,
+                                          cons)
             return hh, kv2
 
         h, kv_new = jax.lax.scan(step, h, (params["layers"], cache["kv"]))
@@ -563,10 +583,7 @@ def decode_step(params, tokens, cache: dict, index, cfg: ModelConfig, *,
     else:
         raise ValueError(at)
 
-    h = L.apply_norm(params["final_norm"], h, cfg.norm)
-    head = params.get("head", params["embed"])
-    logits = L.unembed(head, h)
-    return cons(logits, "logits"), new_cache
+    return _logits(params, h, cfg, cons), new_cache
 
 
 # ----------------------------------------------------------------------------
@@ -601,19 +618,22 @@ def prefill_with_cache(params, tokens, cache: dict, slots, lengths,
 
     def step(carry, lp):
         hh = carry
-        a, kv = L.attention(lp["attn"], L.apply_norm(lp["ln1"], hh, cfg.norm),
-                            cfg, constrain=cons, return_kv=True)
-        hh = hh + a
-        x2 = L.apply_norm(lp["ln2"], hh, cfg.norm)
-        if at == "moe":
-            # single-host capacity path, matching decode_step; ``mesh`` is
-            # accepted for signature parity but EP dispatch is not wired
-            # into serving yet (multi-host serve is a ROADMAP item)
-            mo, _, _, _ = moe_lib.sparse_moe_block(lp["moe"], x2, cfg,
-                                                   mesh=None)
-            hh = hh + mo
-        else:
-            hh = hh + L.apply_mlp(lp["mlp"], x2, cfg.mlp_activation, cons)
+        with jax.named_scope("attn"):
+            a, kv = L.attention(lp["attn"],
+                                L.apply_norm(lp["ln1"], hh, cfg.norm),
+                                cfg, constrain=cons, return_kv=True)
+            hh = hh + a
+        with jax.named_scope("moe" if at == "moe" else "mlp"):
+            x2 = L.apply_norm(lp["ln2"], hh, cfg.norm)
+            if at == "moe":
+                # single-host capacity path, matching decode_step; ``mesh`` is
+                # accepted for signature parity but EP dispatch is not wired
+                # into serving yet (multi-host serve is a ROADMAP item)
+                mo, _, _, _ = moe_lib.sparse_moe_block(lp["moe"], x2, cfg,
+                                                       mesh=None)
+                hh = hh + mo
+            else:
+                hh = hh + L.apply_mlp(lp["mlp"], x2, cfg.mlp_activation, cons)
         return hh, kv
 
     h, (ks, vs) = jax.lax.scan(step, h, params["layers"])  # (L, B', P, ...)
@@ -636,8 +656,6 @@ def prefill_with_cache(params, tokens, cache: dict, slots, lengths,
         "v": cv.at[:, rows, dest].set(vs.astype(cv.dtype), mode="drop"),
     }
 
-    h = L.apply_norm(params["final_norm"], h, cfg.norm)
-    head = params.get("head", params["embed"])
-    logits = cons(L.unembed(head, h), "logits")            # (B', P, V_pad)
+    logits = _logits(params, h, cfg, cons)                 # (B', P, V_pad)
     last = jnp.take_along_axis(logits, (lengths - 1)[:, None, None], axis=1)
     return last[:, 0], new_cache

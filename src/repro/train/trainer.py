@@ -21,6 +21,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ParallelConfig, TrainConfig
@@ -260,6 +261,8 @@ def make_train_step(cfg: ModelConfig, parallel: Optional[ParallelConfig],
         def embed_fn(io, mb):
             return embed_tokens(io, mb["tokens"], cfg, compute_dtype=cd)
 
+        stage_rows = []     # a stage's static PoolRows for one microbatch
+
         def block_fn(lp, h, mb):
             # NOTE: PP stages run the MoE dense path (c_align=1), not the
             # non-PP EP shard_map variant — GSPMD still shards the expert
@@ -270,6 +273,7 @@ def make_train_step(cfg: ModelConfig, parallel: Optional[ParallelConfig],
             # which closes that parity gap.
             h, aux, z, stats = pipeline_stage_forward(
                 lp, h, cfg, sac=parallel.remat_policy)
+            stage_rows.append(stats.rows)
             scal = {"aux": aux, "z": z}
             if cfg.is_moe:
                 scal["counts"] = stats.counts
@@ -322,10 +326,11 @@ def make_train_step(cfg: ModelConfig, parallel: Optional[ParallelConfig],
         if cfg.is_moe:
             # sum over stages = sum over all layers and microbatches; the
             # per-layer mean makes counts sum to the whole-step T*K
-            counts = ssum["counts"].sum(axis=0) / nl
-            metrics["moe_counts"] = counts
-            metrics["moe_load"] = counts / jnp.maximum(counts.sum(), 1.0)
+            metrics["moe_counts"] = ssum["counts"].sum(axis=0) / nl
             metrics["moe_drops"] = ssum["drops"].sum()
+            # a stage holds nl / pp layers; per layer, over all microbatches
+            metrics["moe_rows_computed"] = np.float32(
+                stage_rows[-1].n * pp / nl * n_mb)
         return loss, metrics, grads
 
     def _train_step(state: TrainState, batch: dict):
@@ -341,10 +346,14 @@ def make_train_step(cfg: ModelConfig, parallel: Optional[ParallelConfig],
                                              jnp.float32)
                 m0["moe_drops"] = jnp.zeros((), jnp.float32)
 
+            mb_rows = []    # a microbatch's static moe_rows_computed
+
             def acc_step(carry, mb):
                 gacc, lacc, macc = carry
                 (loss, metrics), grads = jax.value_and_grad(
                     loss_for, has_aux=True)(params, mb)
+                if cfg.is_moe:
+                    mb_rows.append(metrics["moe_rows_computed"])
                 gacc = jax.tree.map(lambda a, g: a + g.astype(jnp.float32),
                                     gacc, grads)
                 macc = {k: macc[k] + metrics[k] for k in macc}
@@ -360,41 +369,41 @@ def make_train_step(cfg: ModelConfig, parallel: Optional[ParallelConfig],
             if cfg.is_moe:
                 # counts/drops are totals, not means: summed over
                 # microbatches they cover the whole global batch
-                counts = macc["moe_counts"]
-                metrics["moe_counts"] = counts
-                metrics["moe_load"] = counts / jnp.maximum(counts.sum(), 1.0)
+                metrics["moe_counts"] = macc["moe_counts"]
                 metrics["moe_drops"] = macc["moe_drops"]
+                metrics["moe_rows_computed"] = mb_rows[-1] * nmb
         else:
             (loss, metrics), grads = jax.value_and_grad(
                 loss_for, has_aux=True)(params, batch)
 
-        # paper: bf16 gradient reduction (cast before the DP reduction that
-        # XLA derives from the state shardings), fp32 update
-        grads = jax.tree.map(lambda g: g.astype(rd).astype(jnp.float32),
-                             grads)
+        with jax.named_scope("optim"):
+            # paper: bf16 gradient reduction (cast before the DP reduction
+            # that XLA derives from the state shardings), fp32 update
+            grads = jax.tree.map(lambda g: g.astype(rd).astype(jnp.float32),
+                                 grads)
 
-        lr = warmup_cosine(state.opt.step, lr_peak=train.lr_peak,
-                           lr_min=train.lr_min,
-                           warmup_steps=train.warmup_steps,
-                           total_steps=train.total_steps)
-        clip_on = None
-        if train.clip_after_warmup_only:
-            clip_on = state.opt.step >= train.warmup_steps
-        if ov_impl != "off":
-            new_params, new_opt, om = overlapped_adamw_update(
-                grads, state.opt, rules=rules, mode=opt_sharding_mode,
-                impl=ov_impl, update_plan=update_plan, lr=lr,
-                beta1=train.beta1, beta2=train.beta2, eps=train.eps,
-                weight_decay=train.weight_decay, grad_clip=train.grad_clip,
-                clip_enabled=clip_on, param_dtype=pd,
-                expert_norm=expert_norm)
-        else:
-            new_params, new_opt, om = adamw_update(
-                grads, state.opt, lr=lr, beta1=train.beta1,
-                beta2=train.beta2, eps=train.eps,
-                weight_decay=train.weight_decay, grad_clip=train.grad_clip,
-                clip_enabled=clip_on, param_dtype=pd,
-                expert_norm=expert_norm)
+            lr = warmup_cosine(state.opt.step, lr_peak=train.lr_peak,
+                               lr_min=train.lr_min,
+                               warmup_steps=train.warmup_steps,
+                               total_steps=train.total_steps)
+            clip_on = None
+            if train.clip_after_warmup_only:
+                clip_on = state.opt.step >= train.warmup_steps
+            if ov_impl != "off":
+                new_params, new_opt, om = overlapped_adamw_update(
+                    grads, state.opt, rules=rules, mode=opt_sharding_mode,
+                    impl=ov_impl, update_plan=update_plan, lr=lr,
+                    beta1=train.beta1, beta2=train.beta2, eps=train.eps,
+                    weight_decay=train.weight_decay,
+                    grad_clip=train.grad_clip, clip_enabled=clip_on,
+                    param_dtype=pd, expert_norm=expert_norm)
+            else:
+                new_params, new_opt, om = adamw_update(
+                    grads, state.opt, lr=lr, beta1=train.beta1,
+                    beta2=train.beta2, eps=train.eps,
+                    weight_decay=train.weight_decay,
+                    grad_clip=train.grad_clip, clip_enabled=clip_on,
+                    param_dtype=pd, expert_norm=expert_norm)
         out_metrics = {"loss": loss, "lr": lr, **metrics, **om}
         return TrainState(new_params, new_opt), out_metrics
 

@@ -73,3 +73,35 @@ def mesh8():
         return r.stdout
 
     return run_sub
+
+
+@pytest.fixture(scope="session")
+def mesh8_start():
+    """Like ``mesh8``, but the snippet starts at once in the background:
+    returns ``start(code) -> wait``, and ``wait()`` returns its stdout and
+    asserts exit 0. A test module overlaps a child's compile with its own
+    work this way."""
+    if not _mesh8_available():
+        pytest.skip(f"cannot force {_N_FORCED} CPU host devices")
+    import subprocess
+    import textwrap
+    children = []
+
+    def start(code: str, timeout: int = 900):
+        p = subprocess.Popen([sys.executable, "-c", textwrap.dedent(code)],
+                             stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                             text=True, env=_mesh8_env())
+        children.append(p)
+
+        def wait() -> str:
+            out, err = p.communicate(timeout=timeout)
+            assert p.returncode == 0, f"STDOUT:\n{out}\nSTDERR:\n{err}"
+            return out
+
+        return wait
+
+    yield start
+    for p in children:
+        if p.poll() is None:
+            p.kill()
+            p.communicate()

@@ -236,7 +236,13 @@ def test_train_step_moe_stats_counts_conserve(nmb):
     np.testing.assert_allclose(float(m["moe_counts"].sum()), B * S * K,
                                atol=1e-3)
     assert float(m["moe_drops"]) == 0.0
-    np.testing.assert_allclose(float(m["moe_load"].sum()), 1.0, atol=1e-5)
+    # a per-layer mean of whole routed pairs
+    summed = np.asarray(m["moe_counts"]) * cfg.num_layers
+    np.testing.assert_array_equal(summed, np.round(summed))
+    # the static pool counter: each microbatch's dropless pool
+    E = cfg.moe.num_experts
+    assert float(m["moe_rows_computed"]) == \
+        nmb * M.dropless_pool_rows(B * S // nmb, K, E)
 
 
 def test_train_step_capacity_reports_drops():
@@ -274,6 +280,8 @@ def test_pp_train_step_moe_stats():
     np.testing.assert_allclose(float(m_pp["moe_counts"].sum()), B * S * K,
                                atol=1e-3)
     assert float(m_pp["moe_drops"]) == 0.0
+    assert float(m_pp["moe_rows_computed"]) == \
+        float(m_ref["moe_rows_computed"])
 
 
 # ---------------------------------------------------------------------------

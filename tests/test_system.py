@@ -36,6 +36,22 @@ def test_train_launcher_resume(tmp_path):
     assert steps[0] == 6 and steps[-1] == 13
 
 
+def test_train_launcher_profile_writes_host_spans(tmp_path):
+    """--profile START:STOP traces those steps into <out>/profile: the
+    trace holds the launcher's host spans around the batch read, the
+    per-step metrics fetch and the checkpoint save."""
+    from jax.profiler import ProfileData
+    from repro.launch.train import run
+    out = tmp_path / "run"
+    run("mula-1b", steps=3, batch=2, seq=32, out=str(out), d_model=64,
+        ckpt_interval=2, profile="1:3")
+    paths = sorted((out / "profile").rglob("*.xplane.pb"))
+    assert len(paths) == 1
+    names = {e.name for p in ProfileData.from_file(str(paths[0])).planes
+             for line in p.lines for e in line.events}
+    assert {"train.input", "train.fetch", "ckpt.save"} <= names
+
+
 def test_serve_loop_generates():
     """Batched greedy decode over a prompt — the serving path end-to-end."""
     from repro.configs import get_config, reduced
