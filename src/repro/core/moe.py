@@ -36,10 +36,14 @@ static.
   are always count-aligned ragged (the grouped-matmul layout), and the
   result is exactly the naive math for ANY routing — independent of
   capacity_factor and of pool-geometry knobs like ``c_align``, which is
-  what makes pp=1 and pp>1 losses bit-comparable at any batch shape.
+  what makes pp=1 and pp>1 losses bit-comparable at any batch shape. The
+  Pallas grouped matmuls stop at the routed rows (``sum(group_sizes)``),
+  so the worst-case tail costs them nothing; the dispatch gather, the
+  SwiGLU and the combine still span the whole pool.
 
-Every path reports ``MoeStats`` (per-expert activation counts + drop
-count) so the train step can surface routing telemetry.
+Every path reports ``MoeStats`` (per-expert activation counts, drop
+count, and the rows of its slot pool and of its grouped matmuls) so the
+train step can surface routing telemetry.
 
 Expert placement (``parallel/placement.py``): every path takes an
 optional ``placement`` — the (E,) *inverse* permutation row mapping
@@ -152,35 +156,35 @@ class DispatchPlan(NamedTuple):
     drops: jax.Array         # scalar: number of dropped (over-capacity) pairs
 
 
-@jax.tree_util.register_static
-@dataclasses.dataclass(frozen=True)
-class PoolRows:
-    """Slot-pool rows the grouped matmul's grid covers, summed over the
-    chips that dispatch distinct tokens. Known from shapes at trace time, so
-    it is a static pytree node (no leaves): it crosses jit, remat, scan and
-    vjp as structure and never becomes a device value."""
-    n: int = 0
-
-    def __add__(self, other: "PoolRows") -> "PoolRows":
-        return PoolRows(self.n + other.n)
-
-
 class MoeStats(NamedTuple):
-    """Per-layer routing telemetry. counts/drops are float32 (not int) so
-    they ride through vjp/scan/psum alongside the loss scalars with zero
-    cotangents; ``rows`` is static (``PoolRows``)."""
+    """Per-layer routing telemetry. Every field is float32 (not int) so it
+    rides through vjp/scan/psum alongside the loss scalars with zero
+    cotangents. The row counts are summed over the chips that dispatch
+    distinct tokens."""
     counts: jax.Array        # (E,) routed (t, k) pairs per global expert
     drops: jax.Array         # () pairs dropped over capacity (0 when dropless)
-    rows: PoolRows = PoolRows()
+    pool_rows: jax.Array     # () slot-pool rows (dispatch, SwiGLU, combine)
+    gemm_rows: jax.Array     # () rows the grouped matmuls compute
 
     @classmethod
     def zero(cls, num_experts: int) -> "MoeStats":
-        return cls(jnp.zeros((num_experts,), jnp.float32),
-                   jnp.zeros((), jnp.float32))
+        z = jnp.zeros((), jnp.float32)
+        return cls(jnp.zeros((num_experts,), jnp.float32), z, z, z)
 
     def __add__(self, other: "MoeStats") -> "MoeStats":
-        return MoeStats(self.counts + other.counts, self.drops + other.drops,
-                        self.rows + other.rows)
+        return MoeStats(*(a + b for a, b in zip(self, other)))
+
+
+def plan_stats(plan: "DispatchPlan", backend: str) -> MoeStats:
+    """One dispatch's MoeStats, before any reduction over chips: the
+    Pallas grouped matmuls visit only the routed groups' tiles
+    (``sum(group_sizes)``), the XLA backends compute every pool row."""
+    gemm = jnp.sum(plan.group_sizes) if backend == "pallas" else \
+        plan.pool_rows
+    return MoeStats(plan.counts.astype(jnp.float32),
+                    plan.drops.astype(jnp.float32),
+                    jnp.float32(plan.pool_rows),
+                    jnp.asarray(gemm, jnp.float32))
 
 
 def make_dispatch_plan(indices: jax.Array, *, num_experts: int,
@@ -408,9 +412,9 @@ def _moe_dense(p, x, moe_cfg, *, backend: str, constrain=None,
                                          dropless=dropless)
     if moe_cfg.num_shared_experts:
         out = out + _shared_expert(p, x)
-    counts = plan.counts if placement is None else plan.counts[placement]
-    stats = MoeStats(counts.astype(jnp.float32),
-                     plan.drops.astype(jnp.float32), PoolRows(plan.pool_rows))
+    stats = plan_stats(plan, backend)
+    if placement is not None:
+        stats = stats._replace(counts=stats.counts[placement])
     return out, r, stats
 
 
@@ -434,39 +438,35 @@ def moe_dropless(p, x, moe_cfg, backend: str = "xla", constrain=None,
 # fsmoe under EP: the five-stage pipeline inside shard_map
 # ----------------------------------------------------------------------------
 
-def _fsmoe_stats(plan_counts, drops, *, ep_axis, ep, batch_axes, manual,
-                 extra_drops=None):
-    """Global MoeStats from one EP rank's dispatch plan.
+def _reduce_stats(stats: MoeStats, axes, batch_axes) -> MoeStats:
+    """Sum ``stats`` over the token-partitioning (batch) axes among
+    ``axes`` and average it over the token-replicating ones (expert-TP,
+    where every rank ran the identical dispatch) — NOT psum over
+    everything, which would multiply-count under expert-TP."""
+    for ax in axes:
+        red = jax.lax.psum if ax in batch_axes else jax.lax.pmean
+        stats = MoeStats(*(red(v, ax) for v in stats))
+    return stats
+
+
+def _fsmoe_stats(local: MoeStats, *, ep_axis, ep, batch_axes, manual):
+    """Global MoeStats from one EP rank's ``plan_stats``.
 
     counts: each rank holds its (EL,) local-expert histogram over the
     ep-gathered tokens — written at its rank's offset of a zero (E,) vector
     and psum'd over ep, which concatenates them in rank order == expert
     order (exact: integer counts plus zeros) and, unlike an all_gather,
-    types the result as replicated over ep for shard_map's check_vma; then
-    token-partitioning axes (batch) psum and token-replicating axes
-    (expert-TP) pmean.
-    drops: psum over ep (each rank drops its own experts' overflow) and over
-    batch axes; pmean over replicating axes — NOT psum over everything,
-    which would multiply-count drops under expert-TP."""
-    el = plan_counts.shape[0]
+    types the result as replicated over ep for shard_map's check_vma.
+    drops and rows: psum over ep (each rank drops its own experts' overflow
+    and computes its own pool). Then ``_reduce_stats`` over the other
+    axes."""
+    el = local.counts.shape[0]
     counts = jax.lax.dynamic_update_slice(
-        jnp.zeros((el * ep,), jnp.float32), plan_counts.astype(jnp.float32),
+        jnp.zeros((el * ep,), jnp.float32), local.counts,
         (jax.lax.axis_index(ep_axis) * el,))
-    counts = jax.lax.psum(counts, ep_axis)
-    drops = drops.astype(jnp.float32)
-    if extra_drops is not None:
-        drops = drops + extra_drops.astype(jnp.float32)
-    drops = jax.lax.psum(drops, ep_axis)
-    for ax in manual:
-        if ax == ep_axis:
-            continue
-        if ax in batch_axes:
-            counts = jax.lax.psum(counts, ax)
-            drops = jax.lax.psum(drops, ax)
-        else:
-            counts = jax.lax.pmean(counts, ax)
-            drops = jax.lax.pmean(drops, ax)
-    return MoeStats(counts, drops)
+    stats = MoeStats(*(jax.lax.psum(v, ep_axis)
+                       for v in local._replace(counts=counts)))
+    return _reduce_stats(stats, sorted(manual - {ep_axis}), batch_axes)
 
 
 def moe_fsmoe_ep(p, x, moe_cfg, *, mesh, ep_axis: str = "model",
@@ -517,11 +517,9 @@ def moe_fsmoe_ep(p, x, moe_cfg, *, mesh, ep_axis: str = "model",
                 raise NotImplementedError(
                     "stage1='a2a' does not compose with expert-TP yet; use "
                     "the allgather Stage 1 for ep x tp plans")
-            out_local, aux, z, stats = _fsmoe_a2a_body(
+            return _fsmoe_a2a_body(
                 gate, up, down, router_w, xl, moe_cfg, ep_axis=ep_axis,
                 ep=ep, manual=manual, batch_axes=batch_axes, placement=pl)
-            pool.append(stats.rows.n)
-            return out_local, aux, z, stats._replace(rows=PoolRows())
         # Router on local tokens (router replicated — paper §3.1).
         r = route(xl, router_w, num_experts=E,
                   top_k=moe_cfg.experts_per_token,
@@ -537,11 +535,11 @@ def moe_fsmoe_ep(p, x, moe_cfg, *, mesh, ep_axis: str = "model",
         r_g = RouterOut(w_g, i_g, r.aux_loss, r.z_loss)
         # ---- Stages 2-5 on the local expert (and d_ff) slice -------------
         rank = jax.lax.axis_index(ep_axis)
+        backend = stage45_backend(moe_cfg)
         out_partial, plan = dispatch_compute_combine(
             gate, up, down, x_g, r_g, moe_cfg,
             expert_offset=rank * EL, local_experts=EL,
-            backend=stage45_backend(moe_cfg), dropless=dropless)
-        pool.append(plan.pool_rows)
+            backend=backend, dropless=dropless)
         with jax.named_scope("exchange"):
             if tp_axis is not None:
                 # expert-TP: sum the per-d_ff-shard partial outputs (the
@@ -556,10 +554,10 @@ def moe_fsmoe_ep(p, x, moe_cfg, *, mesh, ep_axis: str = "model",
             for ax in manual:
                 aux = jax.lax.pmean(aux, ax)
                 z = jax.lax.pmean(z, ax)
-            stats = _fsmoe_stats(plan.counts, plan.drops, ep_axis=ep_axis,
+            stats = _fsmoe_stats(plan_stats(plan, backend), ep_axis=ep_axis,
                                  ep=ep, batch_axes=batch_axes, manual=manual)
         if pl is not None:     # report counts back in global expert order
-            stats = MoeStats(stats.counts[pl], stats.drops)
+            stats = stats._replace(counts=stats.counts[pl])
         return out_local, aux, z, stats
 
     operands = [p["router"], p["gate"], p["up"], p["down"], x]
@@ -568,22 +566,17 @@ def moe_fsmoe_ep(p, x, moe_cfg, *, mesh, ep_axis: str = "model",
     if placement is not None:
         operands.append(jnp.asarray(placement, jnp.int32))
         in_specs.append(P(None))
-    # The replicated outputs type-check: counts and drops are psum'd over
-    # ep, aux and z pmean'd over every axis. Interpret-mode Pallas kernels
+    # The replicated outputs type-check: the stats are psum'd over ep, aux
+    # and z pmean'd over every axis. Interpret-mode Pallas kernels
     # cannot run under the check (ops.vma_checkable); there the EP goldens
     # against the naive MoE (tests/test_distributed.py) are the check.
     from repro.kernels import ops
     check = stage45_backend(moe_cfg) != "pallas" or ops.vma_checkable()
-    # one rank's slot-pool rows: static, so they leave the body through its
-    # trace and not as an output
-    pool = []
     out, aux, z, stats = jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(in_specs),
-        out_specs=(token_spec, P(), P(), MoeStats(P(None), P())),
+        out_specs=(token_spec, P(), P(), MoeStats(P(None), P(), P(), P())),
         axis_names=manual, check_vma=check)(*operands)
-    stats = stats._replace(rows=PoolRows(
-        pool[-1] * ep * math.prod(mesh.shape[a] for a in batch_axes)))
     out = checkpoint_name(out, "moe_out")
     if moe_cfg.num_shared_experts:
         out = out + _shared_expert(p, x)
@@ -654,16 +647,15 @@ def _fsmoe_a2a_body(gate, up, down, router_w, xl, moe_cfg, *, ep_axis, ep,
     local_e = jnp.where(recv_e >= 0, recv_e - rank * EL, EL)   # sentinel EL
     r2 = RouterOut(recv_w[:, None], local_e[:, None].astype(jnp.int32),
                    r.aux_loss, r.z_loss)
-    import dataclasses as _dc
-    inner_cfg = _dc.replace(moe_cfg, experts_per_token=1)
+    inner_cfg = dataclasses.replace(moe_cfg, experts_per_token=1)
     # expected local rows ~ T_loc*K (uniform routing); pool sized with the
     # same capacity slack
     inner_pool = round_up(int(math.ceil(
         moe_cfg.capacity_factor * T_loc * K)), 8)
+    backend = stage45_backend(moe_cfg)
     out_rows, inner_plan = dispatch_compute_combine(
         gate, up, down, recv_x, r2, inner_cfg, expert_offset=0,
-        local_experts=EL, backend=stage45_backend(moe_cfg),
-        pool_rows=inner_pool)
+        local_experts=EL, backend=backend, pool_rows=inner_pool)
 
     # --- reverse all-to-all + per-token sum over K slots ------------------
     back = a2a(out_rows)
@@ -680,13 +672,13 @@ def _fsmoe_a2a_body(gate, up, down, router_w, xl, moe_cfg, *, ep_axis, ep,
         # send-side capacity drops (outer plan) + receive-side pool overflow
         # (inner plan); counts come from the received rows each rank
         # dispatched among its local experts
-        stats = _fsmoe_stats(inner_plan.counts, plan.drops, ep_axis=ep_axis,
-                             ep=ep, batch_axes=batch_axes, manual=manual,
-                             extra_drops=inner_plan.drops)
+        local = plan_stats(inner_plan, backend)
+        local = local._replace(drops=local.drops + plan.drops)
+        stats = _fsmoe_stats(local, ep_axis=ep_axis, ep=ep,
+                             batch_axes=batch_axes, manual=manual)
     if placement is not None:  # back to global expert order
-        stats = MoeStats(stats.counts[placement], stats.drops)
-    return out_local, aux, z, stats._replace(
-        rows=PoolRows(inner_plan.pool_rows))
+        stats = stats._replace(counts=stats.counts[placement])
+    return out_local, aux, z, stats
 
 
 # ----------------------------------------------------------------------------
@@ -723,27 +715,18 @@ def moe_etp_shard_map(p, x, moe_cfg, *, mesh, tp_axis: str = "model",
         out_partial, plan = dispatch_compute_combine(
             gate, up, down, xl, rd, moe_cfg, backend="xla",
             dropless=dropless)
-        pool.append(plan.pool_rows)
         with jax.named_scope("exchange"):
             out = jax.lax.psum(out_partial, tp_axis)
         aux, z = r.aux_loss, r.z_loss
         for ax in manual:
             aux = jax.lax.pmean(aux, ax)
             z = jax.lax.pmean(z, ax)
-        # all E experts are local here (EP=1): counts/drops are per token
-        # shard — psum over token-partitioning axes, pmean over replicating
-        # ones (every tp rank ran the identical dispatch)
-        counts = plan.counts if pl is None else plan.counts[pl]
-        counts = counts.astype(jnp.float32)
-        drops = plan.drops.astype(jnp.float32)
-        for ax in manual:
-            if ax in batch_axes:
-                counts = jax.lax.psum(counts, ax)
-                drops = jax.lax.psum(drops, ax)
-            else:
-                counts = jax.lax.pmean(counts, ax)
-                drops = jax.lax.pmean(drops, ax)
-        return out, aux, z, MoeStats(counts, drops)
+        # all E experts are local here (EP=1): the stats are per token
+        # shard
+        stats = plan_stats(plan, "xla")
+        if pl is not None:
+            stats = stats._replace(counts=stats.counts[pl])
+        return out, aux, z, _reduce_stats(stats, sorted(manual), batch_axes)
 
     operands = [p["router"], p["gate"], p["up"], p["down"], x]
     in_specs = [P(), P(None, None, tp_axis), P(None, None, tp_axis),
@@ -751,14 +734,11 @@ def moe_etp_shard_map(p, x, moe_cfg, *, mesh, tp_axis: str = "model",
     if placement is not None:
         operands.append(jnp.asarray(placement, jnp.int32))
         in_specs.append(P(None))
-    pool = []      # one token shard's slot-pool rows, as under EP
     out, aux, z, stats = jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(in_specs),
-        out_specs=(token_spec, P(), P(), MoeStats(P(None), P())),
+        out_specs=(token_spec, P(), P(), MoeStats(P(None), P(), P(), P())),
         axis_names=manual)(*operands)
-    stats = stats._replace(rows=PoolRows(
-        pool[-1] * math.prod(mesh.shape[a] for a in batch_axes)))
     out = checkpoint_name(out, "moe_out")
     if moe_cfg.num_shared_experts:
         out = out + _shared_expert(p, x)
@@ -785,8 +765,9 @@ def sparse_moe_block(p, x, cfg, *, mesh=None, ep_axis: str = "model",
         out, r = moe_naive(p, xt, m, placement=placement)
         # stats from the router's global ids — already placement-free
         one_hot = jax.nn.one_hot(r.indices, m.num_experts, dtype=jnp.float32)
+        rows = jnp.float32(B * S * m.num_experts)   # every expert, every token
         stats = MoeStats(one_hot.sum((0, 1)), jnp.zeros((), jnp.float32),
-                         PoolRows(B * S * m.num_experts))  # every expert
+                         rows, rows)
         return out.reshape(B, S, d), r.aux_loss, r.z_loss, stats
     use_ep = (m.moe_impl == "fsmoe" and mesh is not None
               and ep_axis in mesh.shape

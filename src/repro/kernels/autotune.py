@@ -397,8 +397,8 @@ def measure_tgmm(dims: Dict[str, int], tiles: Tuple[int, int, int], *,
     def fn(x, dy, group_sizes):
         xp = ops._pad_to(x, tk, 1)
         dyp = ops._pad_to(dy, tn, 1)
-        gids = ops._tile_group_ids(group_sizes, M // tm, tm)
-        out = tgmm_pallas(xp, dyp, gids, G, tile_m=tm, tile_k=tk,
+        gids, n_active = ops._tile_group_ids(group_sizes, M // tm, tm)
+        out = tgmm_pallas(xp, dyp, gids, n_active, G, tile_m=tm, tile_k=tk,
                           tile_n=tn, interpret=ops._interpret())
         out = jnp.where((group_sizes > 0)[:, None, None], out, 0)
         return out[:, :K, :N]

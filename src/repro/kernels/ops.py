@@ -76,14 +76,20 @@ def _pad_to(x, mult, axis):
 
 
 def _tile_group_ids(group_sizes: jax.Array, n_tiles: int, tile_m: int):
-    """tile -> group map (scalar prefetch). Requires group_sizes % tile_m == 0
-    (ensured by the dispatch's alignment). Tiles past sum(group_sizes) are
-    clamped to the last group; their rows are masked out by the callers."""
+    """tile -> group map (scalar prefetch) and the number of tiles the
+    kernels visit, ``ceil(sum(group_sizes) / tile_m)`` but at least one.
+    Requires group_sizes % tile_m == 0 (ensured by the dispatch's
+    alignment), so every visited tile lies inside one group. Tiles past
+    sum(group_sizes) are clamped to the last group; only the one tile a
+    pool with no routed rows still visits reads that entry, and the callers
+    mask what it computes."""
     G = group_sizes.shape[0]
     offsets = jnp.cumsum(group_sizes)
     tile_starts = jnp.arange(n_tiles, dtype=jnp.int32) * tile_m
     gids = jnp.searchsorted(offsets, tile_starts, side="right")
-    return jnp.minimum(gids, G - 1).astype(jnp.int32)
+    n_active = jnp.maximum(1, (offsets[-1] + tile_m - 1) // tile_m)
+    return jnp.minimum(gids, G - 1).astype(jnp.int32), \
+        n_active.astype(jnp.int32)
 
 
 # ----------------------------------------------------------------------------
@@ -120,13 +126,13 @@ def _gmm_fwd_impl(x, w, group_sizes):
     tn = min(tn, N)
     xp = _pad_to(x, tk, 1)
     wp = _pad_to(_pad_to(w, tk, 1), tn, 2)
-    n_tiles = M // tm
-    gids = _tile_group_ids(group_sizes, n_tiles, tm)
-    out = gmm_pallas(xp, wp, gids, tile_m=tm, tile_k=tk, tile_n=tn,
-                     interpret=_interpret())
-    # rows past sum(group_sizes) belong to no group -> zero (ref semantics)
+    gids, n_active = _tile_group_ids(group_sizes, M // tm, tm)
+    out = gmm_pallas(xp, wp, gids, n_active, tile_m=tm, tile_k=tk,
+                     tile_n=tn, interpret=_interpret())
+    # rows past sum(group_sizes) belong to no group -> zero (ref semantics);
+    # the kernel did not write them, so select rather than multiply
     total = jnp.sum(group_sizes)
-    out = out * (jnp.arange(M) < total)[:, None].astype(out.dtype)
+    out = jnp.where((jnp.arange(M) < total)[:, None], out, 0)
     return out[:, :N]
 
 
@@ -153,15 +159,15 @@ def _gmm_bwd(res, dy):
             tm = atm
         tkk = min(atk or tkk, K)
         tnn = min(atn or tnn, N)
-    total = jnp.sum(group_sizes)
-    row_mask = (jnp.arange(M) < total)[:, None]
-    xp = _pad_to(x * row_mask.astype(x.dtype), tkk, 1)
-    dyp = _pad_to(dy * row_mask.astype(dy.dtype), tnn, 1)
-    gids = _tile_group_ids(group_sizes, M // tm, tm)
-    dw = tgmm_pallas(xp, dyp, gids, G, tile_m=tm, tile_k=tkk, tile_n=tnn,
+    # the kernel reads no row past sum(group_sizes), so x and dy go in
+    # unmasked
+    gids, n_active = _tile_group_ids(group_sizes, M // tm, tm)
+    dw = tgmm_pallas(_pad_to(x, tkk, 1), _pad_to(dy, tnn, 1), gids, n_active,
+                     G, tile_m=tm, tile_k=tkk, tile_n=tnn,
                      interpret=_interpret())
     # groups with zero rows have no tiles -> their output block is never
-    # written (uninitialized); their true gradient is zero.
+    # written (uninitialized); their true gradient is zero. A pool with no
+    # rows at all still visits one tile, summed into the empty last group.
     dw = jnp.where((group_sizes > 0)[:, None, None], dw, 0)
     dw = dw[:, :K, :N].astype(w.dtype)
     return dx.astype(x.dtype), dw, None

@@ -20,7 +20,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import moe as moe_lib
@@ -235,22 +234,18 @@ def _scan_layers_aux(stacked, h, body, sac: str, num_experts: int,
     alongside the stacked params, so each layer dispatches against its own
     row (None — an empty pytree — scans through untouched)."""
     fn = block_remat(body, sac)
-    rows = []      # a layer's static PoolRows, kept out of the carry
 
     def step(carry, xs):
         lp, pl = xs
         h, aux, z, st = carry
         h, a, zz, s = fn(lp, h, pl)
-        rows.append(s.rows)
-        return (h, aux + a, z + zz, st + s._replace(rows=st.rows)), None
+        return (h, aux + a, z + zz, st + s), None
 
     (h, aux, z, st), _ = jax.lax.scan(
         step, (h, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32),
                moe_lib.MoeStats.zero(num_experts)),
         (stacked, placement))
-    n_layers = jax.tree.leaves(stacked)[0].shape[0]
-    return h, aux, z, st._replace(
-        rows=moe_lib.PoolRows(rows[-1].n * n_layers))
+    return h, aux, z, st
 
 
 # ----------------------------------------------------------------------------
@@ -377,9 +372,10 @@ def loss_fn(params, batch, cfg: ModelConfig, *, rules=None, mesh=None,
         nl = max(cfg.num_layers, 1)
         metrics["moe_counts"] = st.counts / nl        # per-layer mean -> T*K
         metrics["moe_drops"] = st.drops               # summed over layers
-        # static, so a constant output: the pool rows the grouped matmul
-        # covers in a layer, in the units of moe_counts
-        metrics["moe_rows_computed"] = np.float32(st.rows.n / nl)
+        # per layer, in the units of moe_counts: the slot-pool rows, and
+        # the rows the grouped matmuls compute
+        metrics["moe_rows_computed"] = st.pool_rows / nl
+        metrics["moe_gemm_rows"] = st.gemm_rows / nl
     return total, metrics
 
 

@@ -21,7 +21,6 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ParallelConfig, TrainConfig
@@ -261,8 +260,6 @@ def make_train_step(cfg: ModelConfig, parallel: Optional[ParallelConfig],
         def embed_fn(io, mb):
             return embed_tokens(io, mb["tokens"], cfg, compute_dtype=cd)
 
-        stage_rows = []     # a stage's static PoolRows for one microbatch
-
         def block_fn(lp, h, mb):
             # NOTE: PP stages run the MoE dense path (c_align=1), not the
             # non-PP EP shard_map variant — GSPMD still shards the expert
@@ -273,11 +270,12 @@ def make_train_step(cfg: ModelConfig, parallel: Optional[ParallelConfig],
             # which closes that parity gap.
             h, aux, z, stats = pipeline_stage_forward(
                 lp, h, cfg, sac=parallel.remat_policy)
-            stage_rows.append(stats.rows)
             scal = {"aux": aux, "z": z}
             if cfg.is_moe:
                 scal["counts"] = stats.counts
                 scal["drops"] = stats.drops
+                scal["pool_rows"] = stats.pool_rows
+                scal["gemm_rows"] = stats.gemm_rows
             return h, scal
 
         def head_fn(io, h, mb):
@@ -290,10 +288,12 @@ def make_train_step(cfg: ModelConfig, parallel: Optional[ParallelConfig],
                 "aux": jnp.full((pp,), ca / nl, jnp.float32),
                 "z": jnp.full((pp,), cz / nl, jnp.float32)}
         if cfg.is_moe:
-            # telemetry channels: zero cotangents (counts/drops are derived
-            # from integer routing decisions — no gradient flows through)
+            # telemetry channels: zero cotangents (counts/drops/rows are
+            # derived from integer routing decisions — no gradient flows
+            # through)
             cots["counts"] = jnp.zeros((pp, cfg.moe.num_experts), jnp.float32)
-            cots["drops"] = jnp.zeros((pp,), jnp.float32)
+            for k in ("drops", "pool_rows", "gemm_rows"):
+                cots[k] = jnp.zeros((pp,), jnp.float32)
         mb_b = batch["tokens"].shape[0] // n_mb
         seq = batch["tokens"].shape[1]
         baxes = tuple(rules.batch_axes) if rules is not None else ()
@@ -328,9 +328,9 @@ def make_train_step(cfg: ModelConfig, parallel: Optional[ParallelConfig],
             # per-layer mean makes counts sum to the whole-step T*K
             metrics["moe_counts"] = ssum["counts"].sum(axis=0) / nl
             metrics["moe_drops"] = ssum["drops"].sum()
-            # a stage holds nl / pp layers; per layer, over all microbatches
-            metrics["moe_rows_computed"] = np.float32(
-                stage_rows[-1].n * pp / nl * n_mb)
+            # per layer, over all microbatches
+            metrics["moe_rows_computed"] = ssum["pool_rows"].sum() / nl
+            metrics["moe_gemm_rows"] = ssum["gemm_rows"].sum() / nl
         return loss, metrics, grads
 
     def _train_step(state: TrainState, batch: dict):
@@ -345,15 +345,13 @@ def make_train_step(cfg: ModelConfig, parallel: Optional[ParallelConfig],
                 m0["moe_counts"] = jnp.zeros((cfg.moe.num_experts,),
                                              jnp.float32)
                 m0["moe_drops"] = jnp.zeros((), jnp.float32)
-
-            mb_rows = []    # a microbatch's static moe_rows_computed
+                m0["moe_rows_computed"] = jnp.zeros((), jnp.float32)
+                m0["moe_gemm_rows"] = jnp.zeros((), jnp.float32)
 
             def acc_step(carry, mb):
                 gacc, lacc, macc = carry
                 (loss, metrics), grads = jax.value_and_grad(
                     loss_for, has_aux=True)(params, mb)
-                if cfg.is_moe:
-                    mb_rows.append(metrics["moe_rows_computed"])
                 gacc = jax.tree.map(lambda a, g: a + g.astype(jnp.float32),
                                     gacc, grads)
                 macc = {k: macc[k] + metrics[k] for k in macc}
@@ -367,11 +365,11 @@ def make_train_step(cfg: ModelConfig, parallel: Optional[ParallelConfig],
             loss = loss / nmb
             metrics = {"ce": macc["ce"] / nmb}
             if cfg.is_moe:
-                # counts/drops are totals, not means: summed over
+                # counts/drops/rows are totals, not means: summed over
                 # microbatches they cover the whole global batch
-                metrics["moe_counts"] = macc["moe_counts"]
-                metrics["moe_drops"] = macc["moe_drops"]
-                metrics["moe_rows_computed"] = mb_rows[-1] * nmb
+                for k in ("moe_counts", "moe_drops", "moe_rows_computed",
+                          "moe_gemm_rows"):
+                    metrics[k] = macc[k]
         else:
             (loss, metrics), grads = jax.value_and_grad(
                 loss_for, has_aux=True)(params, batch)

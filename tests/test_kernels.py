@@ -65,6 +65,86 @@ def test_gmm_empty_group_grad_is_zero():
     np.testing.assert_allclose(dw[2], 0.0)
 
 
+# 8 tiles of 8 rows: the routed rows end one tile before the pool does,
+# three quarters of the pool before it, at its start, and at its end with
+# the last group empty
+ROUTED_LAYOUTS = {"tail_one_tile": [16, 8, 24, 8],
+                  "tail_three_quarters": [8, 0, 8, 0],
+                  "no_rows": [0, 0, 0, 0],
+                  "last_group_empty": [32, 16, 16, 0]}
+
+
+@pytest.mark.parametrize("layout", ROUTED_LAYOUTS)
+def test_gmm_visits_only_routed_tiles(layout):
+    """The grids stop at sum(group_sizes): forward and both gradients
+    match the reference with a cotangent that is not zero past the routed
+    rows, the rows past them are exactly zero, and the routed rows are
+    bit-identical to a run of the same kernel over every tile of the pool.
+    The interpreter leaves rows the grid does not visit NaN, so a row the
+    wrapper failed to mask would show."""
+    from repro.kernels.gmm import gmm_pallas
+    M, K, N, tm = 64, 16, 24, 8
+    gs = jnp.array(ROUTED_LAYOUTS[layout], jnp.int32)
+    total = int(gs.sum())
+    x = jax.random.normal(jax.random.PRNGKey(0), (M, K))
+    w = jax.random.normal(jax.random.PRNGKey(1), (4, K, N))
+    c = jax.random.normal(jax.random.PRNGKey(2), (M, N))
+    out = np.asarray(ops.gmm(x, w, gs))
+    np.testing.assert_allclose(out, ref.gmm_ref(x, w, gs), atol=1e-4)
+    assert (out[total:] == 0).all()
+    gids, _ = ops._tile_group_ids(gs, M // tm, tm)
+    full = gmm_pallas(x, w, gids, jnp.int32(M // tm), tile_m=tm, tile_k=K,
+                      tile_n=N, interpret=True)
+    np.testing.assert_array_equal(out[:total], np.asarray(full)[:total])
+    g1 = jax.grad(lambda x, w: (ops.gmm(x, w, gs) * c).sum(),
+                  argnums=(0, 1))(x, w)
+    g2 = jax.grad(lambda x, w: (ref.gmm_ref(x, w, gs) * c).sum(),
+                  argnums=(0, 1))(x, w)
+    np.testing.assert_allclose(g1[0], g2[0], atol=1e-4)
+    np.testing.assert_allclose(g1[1], g2[1], atol=1e-4)
+    assert (np.asarray(g1[0])[total:] == 0).all()
+    assert (np.asarray(g1[1])[np.asarray(gs) == 0] == 0).all()
+
+
+@pytest.mark.parametrize("layout", ROUTED_LAYOUTS)
+def test_gmm_kernels_leave_unvisited_blocks_unwritten(layout):
+    """Under the TPU interpreter, whose fresh buffers hold NaN, the two
+    kernels write exactly the routed rows' output tiles and the weight
+    blocks of the groups with rows (and the last group's, for a pool with
+    no rows, which still visits one tile), and write them right."""
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels.gmm import gmm_pallas, tgmm_pallas
+    if not hasattr(pltpu, "InterpretParams"):
+        pytest.skip("this jax has no TPU interpreter")
+    nan_mem = pltpu.InterpretParams(uninitialized_memory="nan")
+    M, K, N, tm = 64, 16, 24, 8
+    gs = jnp.array(ROUTED_LAYOUTS[layout], jnp.int32)
+    total = int(gs.sum())
+    x = jax.random.normal(jax.random.PRNGKey(0), (M, K))
+    w = jax.random.normal(jax.random.PRNGKey(1), (4, K, N))
+    dy = jax.random.normal(jax.random.PRNGKey(2), (M, N))
+    gids, n_active = ops._tile_group_ids(gs, M // tm, tm)
+    assert int(n_active) == max(1, total // tm)
+    out = np.asarray(gmm_pallas(x, w, gids, n_active, tile_m=tm, tile_k=K,
+                                tile_n=8, interpret=nan_mem))
+    visited = int(n_active) * tm
+    assert np.isnan(out[visited:]).all()
+    np.testing.assert_allclose(out[:total], ref.gmm_ref(x, w, gs)[:total],
+                               atol=1e-4)
+    dw = np.asarray(tgmm_pallas(x, dy, gids, n_active, 4, tile_m=tm,
+                                tile_k=8, tile_n=8, interpret=nan_mem))
+    want = np.asarray(ref.tgmm_ref(x, dy, gs, 4))
+    written = np.asarray(gs) > 0
+    written[-1] |= total == 0
+    for g in range(4):
+        if written[g]:
+            assert np.isfinite(dw[g]).all()
+            if gs[g]:
+                np.testing.assert_allclose(dw[g], want[g], atol=1e-4)
+        else:
+            assert np.isnan(dw[g]).all()
+
+
 @pytest.mark.parametrize("T,K,D", [(32, 2, 48), (64, 8, 16), (16, 1, 512)])
 def test_combine_kernel(T, K, D):
     rows = jax.random.normal(jax.random.PRNGKey(0), (T, K, D))

@@ -239,10 +239,39 @@ def test_train_step_moe_stats_counts_conserve(nmb):
     # a per-layer mean of whole routed pairs
     summed = np.asarray(m["moe_counts"]) * cfg.num_layers
     np.testing.assert_array_equal(summed, np.round(summed))
-    # the static pool counter: each microbatch's dropless pool
+    # the pool counter: each microbatch's dropless pool, every row of
+    # which this (XLA) backend's grouped matmuls compute
     E = cfg.moe.num_experts
     assert float(m["moe_rows_computed"]) == \
         nmb * M.dropless_pool_rows(B * S // nmb, K, E)
+    assert float(m["moe_gemm_rows"]) == float(m["moe_rows_computed"])
+
+
+def _aligned_rows(counts, align):
+    """Rows of a pool's routed groups: each expert's count rounded up to
+    the grouped matmul's tile."""
+    c = np.rint(np.asarray(counts)).astype(np.int64)
+    return int(np.sum(-(-c // align) * align))
+
+
+def test_train_step_gemm_rows_count_the_routed_tiles():
+    """On the Pallas backend the grouped matmuls stop at the routed rows:
+    ``moe_gemm_rows`` is the layer's tile-aligned group sizes, a device
+    value below the worst-case pool that ``moe_rows_computed`` still
+    reports, and every row of it but the alignment carries a routed
+    pair."""
+    from repro.parallel.plan import KernelPlan, use_kernel_plan
+    cfg = dataclasses.replace(_moe_train_cfg(), num_layers=1)
+    B, S, K, E, tm = 4, 32, cfg.moe.experts_per_token, \
+        cfg.moe.num_experts, 8
+    with use_kernel_plan(KernelPlan(backend="pallas", tile_m=tm)):
+        _, m = _run_step(cfg, ParallelConfig(moe_dispatch="dropless"),
+                         batch=B, seq=S)
+    gemm = float(m["moe_gemm_rows"])
+    assert gemm == _aligned_rows(m["moe_counts"], tm)
+    assert float(m["moe_rows_computed"]) == \
+        M.dropless_pool_rows(B * S, K, E, align=tm) > gemm
+    assert 0 < float(np.sum(m["moe_counts"])) / gemm <= 1
 
 
 def test_train_step_capacity_reports_drops():
@@ -332,6 +361,56 @@ def test_dropless_ep_tp_matches_naive_mesh8(mesh8):
         print("DROPLESS-EP-TP-OK")
     """, timeout=1200)
     assert "DROPLESS-EP-TP-OK" in out
+
+
+@pytest.mark.distributed
+@pytest.mark.slow
+def test_dropless_ep_gemm_rows_mesh8(mesh8):
+    """The grouped-matmul row counter under EP=2 on the Pallas kernels, on
+    a (data=2, ep=2, tp=2) mesh: each (data, ep) rank computes its local
+    experts' tile-aligned groups over the tokens its data shard gathered;
+    the counter sums those over data and ep, and counts once over tp,
+    whose ranks dispatch the same tokens. The pool counter sums the four
+    dispatching ranks' worst-case pools."""
+    out = mesh8("""
+        import jax, numpy as np
+        from jax.sharding import PartitionSpec as P, NamedSharding
+        from jax.sharding import AxisType
+        from repro.configs.base import ModelConfig, MoEConfig
+        from repro.core import moe as M
+        from repro.parallel.plan import KernelPlan, use_kernel_plan
+        mesh = jax.make_mesh((2, 2, 2), ("data", "ep", "tp"),
+                             axis_types=(AxisType.Auto,)*3)
+        cfg = ModelConfig(name="t", arch_type="moe", num_layers=1, d_model=32,
+                          num_heads=2, num_kv_heads=2, d_ff=0, vocab_size=64,
+                          moe=MoEConfig(num_experts=4, experts_per_token=2,
+                                        d_ff_expert=16, moe_impl="fsmoe",
+                                        dispatch="dropless"))
+        p = M.init_moe_block(jax.random.PRNGKey(0), cfg)
+        x = jax.random.normal(jax.random.PRNGKey(1), (64, 32))
+        pspec = {"router": P(), "gate": P("ep", None, "tp"),
+                 "up": P("ep", None, "tp"), "down": P("ep", "tp", None)}
+        ps = jax.tree.map(lambda a, s: jax.device_put(a, NamedSharding(mesh, s)),
+                          p, pspec)
+        xs = jax.device_put(x, NamedSharding(mesh, P(("data", "ep"), None)))
+        with use_kernel_plan(KernelPlan(backend="pallas", tile_m=8)):
+            stats = jax.jit(lambda p, x: M.sparse_moe_block(
+                p, x.reshape(4, 16, 32), cfg, mesh=mesh, ep_axis="ep",
+                tp_axis="tp", batch_axes=("data",))[3])(ps, xs)
+            # each data shard's 32 tokens, dispatched over all 4 experts
+            shard = [M.moe_dropless(p, x[32 * d:32 * (d + 1)], cfg.moe,
+                                    backend="pallas")[2].counts
+                     for d in range(2)]
+        counts = np.rint(np.asarray(shard)).astype(int)
+        np.testing.assert_array_equal(counts.sum(0), np.asarray(stats.counts))
+        want = int(np.sum(-(-counts // 8) * 8))
+        assert float(stats.gemm_rows) == want, (stats.gemm_rows, want)
+        assert float(stats.pool_rows) == 4 * M.dropless_pool_rows(
+            32, 2, 2, align=8) > want
+        assert 0 < 64 * 2 / float(stats.gemm_rows) <= 1
+        print("EP-GEMM-ROWS-OK")
+    """, timeout=900)
+    assert "EP-GEMM-ROWS-OK" in out
 
 
 @pytest.mark.distributed

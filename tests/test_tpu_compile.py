@@ -89,6 +89,11 @@ def _cases():
         return jax.grad(lambda r, c: ops.combine(r, c).astype(f32).sum(),
                         argnums=(0, 1))(rows, wts)
 
+    # one EP=4 chip's dropless pool at the benchmark's 3x2048 tokens per
+    # chip: all 4 chips' T*K rows plus a tile per local expert, of which
+    # the grid visits only the routed tiles
+    el = e // 4
+    m_ep = 4 * 3 * T * k + 128 * el
     attn = [((1, SEQ, w["h"], w["hd"]), bf)] * 3
     return {
         "gmm_fwd_gate_up": (ops.gmm, [((m, d), bf), ((e, d, f), bf),
@@ -97,6 +102,10 @@ def _cases():
                                    ((e,), i32)]),
         # backward: dx through gmm on the transposed stack, dw through tgmm
         "gmm_bwd": (gmm_grads, [((m, d), bf), ((e, d, f), bf), ((e,), i32)]),
+        "gmm_fwd_ep4_pool": (ops.gmm, [((m_ep, d), bf), ((el, d, f), bf),
+                                       ((el,), i32)]),
+        "gmm_bwd_ep4_pool": (gmm_grads, [((m_ep, d), bf), ((el, d, f), bf),
+                                         ((el,), i32)]),
         "fused_swiglu": (ops.fused_swiglu, [((m, f), bf), ((m, f), bf)]),
         "combine_fwd": (ops.combine, [((T, k, d), bf), ((T, k), bf)]),
         "combine_bwd": (combine_grads, [((T, k, d), bf), ((T, k), bf)]),
@@ -111,7 +120,9 @@ def _cases():
 
 
 @pytest.mark.parametrize("name", ["gmm_fwd_gate_up", "gmm_fwd_down",
-                                  "gmm_bwd", "fused_swiglu", "combine_fwd",
+                                  "gmm_bwd", "gmm_fwd_ep4_pool",
+                                  "gmm_bwd_ep4_pool", "fused_swiglu",
+                                  "combine_fwd",
                                   "combine_bwd", "combine_bwd_f32",
                                   "token_counts", "flash_attention_fwd"])
 def test_kernel_compiles_for_v5e(one_chip, name):
