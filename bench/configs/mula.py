@@ -2,7 +2,9 @@
 decoder), for training: loss, gradients and AdamW, in straightforward
 ``jax.numpy`` at float32 with every matrix product at ``HIGHEST``
 precision. It imports nothing of the program and takes its weights from
-``bench/weights.py``.
+``bench/weights.py``, drawn in this module's ``layout``; its FLOP counts
+(``flops_per_token``, ``expert_gemm_flops``) feed ``mfu``, ``step_mfu``
+and ``expert_gemm_roofline``.
 
 The model, as the configuration file states it:
 
@@ -51,6 +53,86 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 HIGHEST = jax.lax.Precision.HIGHEST
 ROW_BLOCK = 1024
+VOCAB_ALIGN = 256
+# read by this reference alone: the program's norms fix eps at 1e-6 and
+# have no field for it
+REFERENCE_KEYS = ("norm_eps",)
+
+
+def padded_vocab(c: dict) -> int:
+    return -(-c["vocab_size"] // VOCAB_ALIGN) * VOCAB_ALIGN
+
+
+def layout(c: dict) -> list:
+    """The training program's parameter tree (its checkpoint format) as
+    [(path, shape, scale)] in the order of ``jax.tree.leaves`` of the
+    nested tree; scale None means ones. ``embed``/``head`` tables padded
+    to a multiple of 256 rows, ``final_norm``, and one stacked ``layers``
+    group whose leading axis is the layer."""
+    d, L, vp = c["d_model"], c["num_layers"], padded_vocab(c)
+    q = c["num_heads"] * c["head_dim"]
+    kv = c["num_kv_heads"] * c["head_dim"]
+    s = 1.0 / math.sqrt(d)
+    out = [
+        (("embed", "table"), (vp, d), 0.02),
+        (("final_norm", "scale"), (d,), None),
+        (("head", "table"), (vp, d), 0.02),
+        (("layers", "attn", "wk"), (L, d, kv), s),
+        (("layers", "attn", "wo"), (L, q, d), s),
+        (("layers", "attn", "wq"), (L, d, q), s),
+        (("layers", "attn", "wv"), (L, d, kv), s),
+        (("layers", "ln1", "scale"), (L, d), None),
+        (("layers", "ln2", "scale"), (L, d), None),
+    ]
+    if c["arch_type"] == "moe":
+        e, f = c["num_experts"], c["d_ff_expert"]
+        out += [
+            (("layers", "moe", "down"), (L, e, f, d), 1.0 / math.sqrt(f)),
+            (("layers", "moe", "gate"), (L, e, d, f), s),
+            (("layers", "moe", "router"), (L, d, e), s),
+            (("layers", "moe", "up"), (L, e, d, f), s),
+        ]
+    else:
+        f = c["d_ff"]
+        out += [
+            (("layers", "mlp", "down"), (L, f, d), 1.0 / math.sqrt(f)),
+            (("layers", "mlp", "gate"), (L, d, f), s),
+            (("layers", "mlp", "up"), (L, d, f), s),
+        ]
+    return out
+
+
+def flops_per_token(c: dict, seq_len: int) -> dict:
+    """Training FLOPs per token, by part: 6 x the matrix-product parameters
+    a token uses (forward 2, backward 4), plus causal attention scores
+    (6 x heads x head_dim x seq_len: the QK^T and PV products over on
+    average seq_len / 2 keys, forward and backward). Embedding lookups,
+    elementwise work and what remat recomputes are not counted. The head
+    counts the vocabulary's ``vocab_size`` rows, not the padded table."""
+    d, L = c["d_model"], c["num_layers"]
+    q = c["num_heads"] * c["head_dim"]
+    kv = c["num_kv_heads"] * c["head_dim"]
+    parts = {
+        "head": 6 * d * c["vocab_size"],
+        "attention_projections": 6 * L * d * (2 * q + 2 * kv),
+        "attention_scores": 6 * L * q * seq_len,
+    }
+    if c["arch_type"] == "moe":
+        parts["experts"] = 6 * L * c["experts_per_token"] * 3 * d * \
+            c["d_ff_expert"]
+        parts["router"] = 6 * L * d * c["num_experts"]
+    else:
+        parts["mlp"] = 6 * L * 3 * d * c["d_ff"]
+    return parts
+
+
+def expert_gemm_flops(c: dict, tokens: int) -> int:
+    """FLOPs of the routed rows' expert GEMMs in a training step: T*K rows
+    through three d x f matrices, forward and backward."""
+    if c["arch_type"] != "moe":
+        return 0
+    return (tokens * c["experts_per_token"] * 3 * 2 * c["d_model"]
+            * c["d_ff_expert"] * 3 * c["num_layers"])
 
 
 def _round(x, dtype):

@@ -9,6 +9,17 @@ the correctness check. Each metric of ``BENCHMARK.json`` that applies to
 the cell is read by ``bench/metrics/<metric>.py``. Peaks come from
 ``bench/peaks.json`` by ``device_kind``.
 
+The reference module owns its architecture: it exports ``make_step``
+(the float32 training step, with the ``quant`` control and the
+``drop_half`` and ``local_experts`` faults), ``layout`` (the weight
+layout, the program's parameter tree), ``flops_per_token`` and
+``expert_gemm_flops`` (the FLOP counts of ``mfu``, ``step_mfu`` and
+``expert_gemm_roofline``), and may declare ``REFERENCE_KEYS``, the
+configuration keys it alone reads. The configuration's other keys reach
+the program's config objects by field name (``bench/system.py``), so a new
+architecture comes as new files: a configuration, its reference module, a
+traffic mix, a cell and any metric readers it needs.
+
 A run: set-up (find the chips, write the cell's token shards, make the
 state on the device from the seed at the step counter ``first_step``,
 compile the step with the state donated, drive the first three steps
@@ -32,6 +43,8 @@ import tempfile
 import time
 
 import numpy as np
+
+from bench import configs
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "bench")
@@ -80,7 +93,7 @@ class Cell:
         return self.batch * self.seq_len
 
     def reference(self):
-        return importlib.import_module(f"bench.configs.{self.c['reference']}")
+        return configs.reference(self.c)
 
 
 def load_cell(workload: str, sizes: dict | None = None) -> Cell:

@@ -5,6 +5,8 @@ and a cell file of this benchmark. This is the only module of the
 benchmark that imports the program."""
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
@@ -18,7 +20,7 @@ from repro.parallel.sharding import batch_sharding
 from repro.train.trainer import (TrainState, make_train_step,
                                  train_state_shardings)
 
-from bench import weights
+from bench import configs, weights
 
 __all__ = ["ShardedDataLoader", "Program", "enable_compile_cache"]
 
@@ -32,34 +34,56 @@ def enable_compile_cache() -> str:
     return d
 
 
+# keys that document the configuration and configure nothing
+DOC_KEYS = frozenset({"reference", "deployment", "assumed"})
+DOC_PREFIXES = ("source", "published_")
+MODEL_KEYS = frozenset(f.name for f in dataclasses.fields(ModelConfig)) - {
+    "moe", "ssm"}
+MOE_KEYS = frozenset(f.name for f in dataclasses.fields(MoEConfig))
+# seq_len and global_batch are the traffic's
+RECIPE_KEYS = frozenset(f.name for f in dataclasses.fields(TrainConfig)) - {
+    "seq_len", "global_batch", "seed"}
+def _pick(c: dict, keys) -> dict:
+    return {k: v for k, v in c.items() if k in keys}
+
+
+def check_keys(c: dict) -> None:
+    """Every key of the configuration file reaches something that reads
+    it: the program's ``ModelConfig``, ``MoEConfig`` or ``TrainConfig`` by
+    field name, or the plain reference's ``REFERENCE_KEYS``; or it
+    documents the configuration; or it is ``master_dtype``, which has to
+    be float32, the master weights and AdamW moments of ``init_state``.
+    Any other key, or another master, would build a different model than
+    the file states, so it stops the run with its name."""
+    known = (MODEL_KEYS | MOE_KEYS | RECIPE_KEYS | {"master_dtype"} | DOC_KEYS
+             | set(getattr(configs.reference(c), "REFERENCE_KEYS", ())))
+    unknown = sorted(k for k in c
+                     if k not in known and not k.startswith(DOC_PREFIXES))
+    if unknown:
+        raise KeyError(f"configuration {c.get('name')!r}: no part of the "
+                       f"program or of its reference reads {unknown}")
+    if c.get("master_dtype", "float32") != "float32":
+        raise ValueError(f"configuration {c.get('name')!r}: master_dtype "
+                         f"{c['master_dtype']!r}, but the state is float32")
+
+
 def model_config(c: dict) -> ModelConfig:
+    """The program's ``ModelConfig``: each key of the file that names one
+    of its fields, and for ``arch_type`` moe a ``MoEConfig`` of each key
+    that names one of its fields (``moe_impl`` fsmoe unless the file says
+    otherwise)."""
+    check_keys(c)
     moe = None
     if c["arch_type"] == "moe":
-        moe = MoEConfig(num_experts=c["num_experts"],
-                        experts_per_token=c["experts_per_token"],
-                        d_ff_expert=c["d_ff_expert"],
-                        router_aux_coef=c["router_aux_coef"],
-                        router_z_coef=c["router_z_coef"],
-                        moe_impl="fsmoe")
-    return ModelConfig(
-        name=c["name"], arch_type=c["arch_type"], num_layers=c["num_layers"],
-        d_model=c["d_model"], num_heads=c["num_heads"],
-        num_kv_heads=c["num_kv_heads"], head_dim=c["head_dim"],
-        d_ff=c["d_ff"], vocab_size=c["vocab_size"], moe=moe,
-        rope_theta=c["rope_theta"], norm=c["norm"],
-        tie_embeddings=c["tie_embeddings"])
+        moe = MoEConfig(**{"moe_impl": "fsmoe", **_pick(c, MOE_KEYS)})
+    return ModelConfig(**_pick(c, MODEL_KEYS), moe=moe)
 
 
 def train_config(c: dict, seq_len: int, global_batch: int) -> TrainConfig:
-    return TrainConfig(
-        seq_len=seq_len, global_batch=global_batch, lr_peak=c["lr_peak"],
-        lr_min=c["lr_min"], warmup_steps=c["warmup_steps"],
-        total_steps=c["total_steps"], weight_decay=c["weight_decay"],
-        beta1=c["beta1"], beta2=c["beta2"], eps=c["eps"],
-        grad_clip=c["grad_clip"],
-        clip_after_warmup_only=c["clip_after_warmup_only"],
-        grad_reduce_dtype=c["grad_reduce_dtype"],
-        param_dtype=c["param_dtype"], compute_dtype=c["compute_dtype"])
+    """The program's ``TrainConfig``: the traffic's sequence length and
+    batch, and each key of the file that names one of its fields."""
+    return TrainConfig(seq_len=seq_len, global_batch=global_batch,
+                       **_pick(c, RECIPE_KEYS))
 
 
 class Program:

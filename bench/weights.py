@@ -1,61 +1,27 @@
 """Weights from the seed, made by the benchmark and not by the program.
 
 The layout is the training program's parameter tree (its checkpoint
-format): ``embed``/``head`` tables padded to a multiple of 256 rows,
-``final_norm``, and one stacked ``layers`` group whose leading axis is the
-layer. The harness checks this layout against the program's own before a
-run, so a change of format stops the run instead of feeding it wrong
-weights. Every leaf is drawn from its own key, so one leaf can be made
-again on its own (``leaf``) and equals the one that ``make`` gives.
+format), as the configuration's plain reference states it (``layout`` of
+``bench/configs/<reference>.py``). The harness checks this layout against
+the program's own before a run, so a change of format stops the run
+instead of feeding it wrong weights. Every leaf is drawn from its own key,
+so one leaf can be made again on its own (``leaf``) and equals the one
+that ``make`` gives.
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
 
-VOCAB_ALIGN = 256
-
-
-def padded_vocab(c: dict) -> int:
-    return -(-c["vocab_size"] // VOCAB_ALIGN) * VOCAB_ALIGN
+from bench import configs
 
 
 def layout(c: dict) -> list:
     """[(path, shape, scale)] in the order of ``jax.tree.leaves`` of the
-    nested tree; scale None means ones."""
-    d, L, vp = c["d_model"], c["num_layers"], padded_vocab(c)
-    q = c["num_heads"] * c["head_dim"]
-    kv = c["num_kv_heads"] * c["head_dim"]
-    s = 1.0 / math.sqrt(d)
-    out = [
-        (("embed", "table"), (vp, d), 0.02),
-        (("final_norm", "scale"), (d,), None),
-        (("head", "table"), (vp, d), 0.02),
-        (("layers", "attn", "wk"), (L, d, kv), s),
-        (("layers", "attn", "wo"), (L, q, d), s),
-        (("layers", "attn", "wq"), (L, d, q), s),
-        (("layers", "attn", "wv"), (L, d, kv), s),
-        (("layers", "ln1", "scale"), (L, d), None),
-        (("layers", "ln2", "scale"), (L, d), None),
-    ]
-    if c["arch_type"] == "moe":
-        e, f = c["num_experts"], c["d_ff_expert"]
-        out += [
-            (("layers", "moe", "down"), (L, e, f, d), 1.0 / math.sqrt(f)),
-            (("layers", "moe", "gate"), (L, e, d, f), s),
-            (("layers", "moe", "router"), (L, d, e), s),
-            (("layers", "moe", "up"), (L, e, d, f), s),
-        ]
-    else:
-        f = c["d_ff"]
-        out += [
-            (("layers", "mlp", "down"), (L, f, d), 1.0 / math.sqrt(f)),
-            (("layers", "mlp", "gate"), (L, d, f), s),
-            (("layers", "mlp", "up"), (L, d, f), s),
-        ]
-    return out
+    nested tree; scale None means ones. The one place that takes the layout
+    from the reference: ``make``, the program's layout check and the
+    parameter count of ``bench/scopes.py`` all read it here."""
+    return configs.reference(c).layout(c)
 
 
 def seed_words(seed: int) -> jax.Array:

@@ -9,7 +9,7 @@ import re
 import jax
 import pytest
 
-from bench import check, harness, weights
+from bench import check, configs, harness, weights
 
 ROOT = harness.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -67,7 +67,8 @@ def test_cell_files_agree_with_manifest(workload):
     for k in conf["reduced"]:
         assert k in cell.c
     importlib.import_module(f"bench.laws.{cell.mix['law']}")
-    assert hasattr(cell.reference(), "make_step")
+    ref = cell.reference()
+    assert all(callable(getattr(ref, n, None)) for n in configs.EXPORTS)
     lim = cell.spec["limits"]
     assert lim and set(lim) <= set(check.NAMES)
     assert all(0 < v < 1 for v in lim.values())
